@@ -132,27 +132,15 @@ def substrate_sweep_bench() -> List[Row]:
             "pim_substrate.analog_pallas_temp_mem_ratio",
             mems["analog"] / max(mems["analog-pallas"], 1.0),
             ">1 expected: no (planes,chunks,M,N) intermediate in HBM"))
-    # chunk-sum transient budget: the readout kernel folds the chunk axis
-    # in sub-blocks, so the live per-plane-pair tile must stay under
-    # 2 MiB at the serve-shaped default (whole-tile folding was 8 MiB)
-    from repro.kernels.analog_readout.analog_readout import \
-        chunk_transient_bytes
-    transient = chunk_transient_bytes()
-    assert transient < 2 * 2**20, (
-        f"analog-readout chunk-sum transient {transient / 2**20:.2f} MiB "
-        "exceeds the 2 MiB per-plane-pair budget — was the chunk-axis "
-        "sub-blocking (DEFAULT_CHUNK_BLOCK) widened?")
     if mems["analog-pallas"] is not None:
-        # whole-pipeline guard: an unblocked fold would put the 8 MiB
-        # tile (per pair) back into the compiled temp allocation
+        # the readout kernel walks the chunk axis one WDM chunk at a time;
+        # a whole-tile chunk fold would put an 8 MiB (KC, bm, bn) tile per
+        # plane pair back into the compiled temp allocation
         temp_mib = mems["analog-pallas"] / 2**20
         assert mems["analog-pallas"] < 8 * 2**20, (
             f"analog-pallas compiled temp {temp_mib:.2f} MiB at "
             f"{SWEEP_M}x{SWEEP_K}x{SWEEP_N} — chunk-sum transient "
             "regression?")
-    rows.append(("pim_substrate.analog_pallas.chunk_transient_mib",
-                 transient / 2**20,
-                 "live per-plane-pair chunk-sum tile; asserted < 2 MiB"))
     return rows
 
 

@@ -81,6 +81,14 @@ class ModelConfig:
             return 0
         return self.sliding_window
 
+    def with_depth(self, num_layers: int) -> "ModelConfig":
+        """Depth-only cut to ``num_layers`` layers (and at most as many
+        encoder layers); every published width and the full vocabulary
+        stay."""
+        return dataclasses.replace(
+            self, num_layers=num_layers,
+            encoder_layers=min(self.encoder_layers, num_layers))
+
     def reduced(self, num_layers: int = 2, d_model: int = 64,
                 vocab: int = 128) -> "ModelConfig":
         """Smoke-test configuration of the same family (small everything)."""

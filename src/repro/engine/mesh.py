@@ -42,6 +42,12 @@ so a shard that sees only a subset would auto-range a different lsb —
 silently not bit-identical. Expert splits are fine (the range is
 per-expert already).
 
+A split axis of size 1 (a data-only mesh) still stamps every plan that
+may split: nothing is divided, but the matmul then runs under
+``shard_map`` with its batch over the data axes. XLA cannot partition a
+Mosaic kernel by itself, so on a TPU an unstamped Pallas plan fed a
+data-sharded batch does not compile.
+
 Everything here is CPU-testable with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 """
@@ -52,7 +58,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import pim
@@ -130,9 +136,14 @@ def shard_dense_plan(plan: pim.DensePlan, mesh: Mesh, kind: str,
     if kind not in ("col", "row"):
         raise ValueError(f"dense plans shard 'col' or 'row', got {kind!r}")
     tp = mesh.shape[axis]
-    if tp == 1:
-        return plan
     sub = plan.substrate
+    if tp == 1:
+        if sub not in _COL_SUBSTRATES:
+            return plan
+        # nothing to split, but the stamp still routes the matmul through
+        # shard_map with the batch over the data axes: a Mosaic kernel
+        # cannot be partitioned automatically
+        kind = "col"
     if kind == "col" and sub not in _COL_SUBSTRATES:
         raise ValueError(
             f"substrate {sub!r} cannot column-split bit-identically (the "
@@ -180,8 +191,6 @@ def shard_expert_plan(plan: pim.ExpertStackedPlan, mesh: Mesh,
     substrate (per-expert math, including the per-expert analog
     auto-range, is self-contained)."""
     tp = mesh.shape[axis]
-    if tp == 1:
-        return plan
     if plan.num_experts % tp:
         raise ValueError(
             f"expert split needs num_experts ({plan.num_experts}) "
@@ -301,7 +310,7 @@ def _col_matmul(sub, x: jax.Array, plan: pim.DensePlan,
         in_specs.append(P(axis))
         args.append(bias.astype(jnp.float32).reshape(-1))
     out = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=P(b, axis), check_rep=False)(*args)
+                    out_specs=P(b, axis), check_vma=False)(*args)
     return out.reshape(orig[:-1] + (plan.n,))
 
 
@@ -331,7 +340,7 @@ def _row_matmul(sub, x: jax.Array, plan: pim.DensePlan,
 
     acc = shard_map(body, mesh=mesh,
                     in_specs=(P(None, b, axis), P(None, axis, None)),
-                    out_specs=P(b, None), check_rep=False
+                    out_specs=P(b, None), check_vma=False
                     )(a_planes, plan.planes)
     out = acc[:, :plan.n].astype(jnp.float32) * a_q.scale * plan.scale
     if bias is not None:
@@ -377,7 +386,7 @@ def _expert_matmul(sub, x: jax.Array, plan: pim.ExpertStackedPlan,
         in_specs=(x_spec, leaf_spec, leaf_spec, P(axis, None, None, None),
                   leaf_spec),
         out_specs=P(*((None,) * (x.ndim + (0 if paired else 1)))),
-        check_rep=False,
+        check_vma=False,
     )(x, d.values, d.scale, d.planes, d.padded_scale)
     return out
 

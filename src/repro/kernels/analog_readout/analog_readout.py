@@ -32,7 +32,10 @@ arrives in SMEM and each grid step folds its ``program_id`` triple into a
 staying reproducible and vmap-safe (expert stacks batch the seed).
 ``pltpu.prng_seed`` would be the on-device alternative, but it has no
 interpret-mode lowering on CPU, and bit-agreement *between the two
-passes* is the hard requirement here.
+passes* is the hard requirement here. The noise path runs in interpret
+mode only: compiled for a TPU, the per-tile (pairs, KC, bm, bn) draw
+overflows VMEM at prefill tiles, so a compiled noise call raises
+:class:`NotImplementedError` (see :func:`_check_noise_lowerable`).
 
 Scale/bias vectors reuse the lane-padded (SUBLANE/LANE) register-tile
 layout of the exact kernel so compiled Mosaic lowering never sees a
@@ -56,32 +59,6 @@ DEFAULT_BN = 256
 # grid steps, where step count dominates wall clock): (128, 256, 512)
 # minimizes steps across decode- and prefill-shaped problems.
 DEFAULT_BK = 512
-# The kernel bodies fold over the chunk axis in sub-blocks of
-# ``chunk_block`` WDM chunks, so the live chunk-sum transient per plane
-# pair is (chunk_block, bm, bn) f32 — not (KC, bm, bn). At the defaults
-# (bk=512, chunk=8 -> KC=64) an unblocked tile would be
-# 64*128*256*4 B = 8 MiB, oversized for a real 16 MiB-VMEM core; with
-# chunk_block=8 it is 1 MiB. Max- and int32-code accumulation are both
-# associative, so sub-blocking is bit-identical to the whole-tile fold.
-DEFAULT_CHUNK_BLOCK = 8
-
-
-def chunk_transient_bytes(bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                          chunk_block: int = DEFAULT_CHUNK_BLOCK) -> int:
-    """Size of the live per-plane-pair chunk-sum transient — the tile
-    the deterministic readout path materializes at once (noise runs draw
-    a full per-tile normal tensor on top; that path trades VMEM for
-    two-pass bit-agreement)."""
-    return chunk_block * bm * bn * 4
-
-
-def _chunk_block_for(kc: int, chunk_block: int) -> int:
-    """Largest divisor of ``kc`` not exceeding the requested block (the
-    fori_loop needs equal-size sub-blocks)."""
-    cb = max(1, min(chunk_block, kc))
-    while kc % cb:
-        cb -= 1
-    return cb
 
 
 def analog_tiles(m: int, k: int, n: int, chunk: int,
@@ -97,6 +74,18 @@ def analog_tiles(m: int, k: int, n: int, chunk: int,
     return bm, bn, bk
 
 
+def _check_noise_lowerable(has_noise: bool, interpret: bool) -> None:
+    """The transmission-noise path has no compiled (Mosaic) form yet: its
+    per-tile normal draw is (pairs, KC, bm, bn) f32 — 8 MiB per plane
+    pair at the default tiles — which exhausts VMEM at prefill shapes.
+    Refuse it loudly rather than fall back to the interpreter."""
+    if has_noise and not interpret:
+        raise NotImplementedError(
+            "analog-pallas transmission-noise path (sigma > 0 with a seed) "
+            "has no compiled TPU lowering; run the noise study with "
+            "interpret=True or on the 'analog' jnp substrate")
+
+
 def _tile_noise(seed, npairs: int, kc: int, bm: int, bn: int) -> jax.Array:
     """Per-tile standard normals from a threaded key: the (i, j, s) grid
     position folds into the seed, so the full-scale and readout passes —
@@ -107,41 +96,40 @@ def _tile_noise(seed, npairs: int, kc: int, bm: int, bn: int) -> jax.Array:
     return jax.random.normal(key, (npairs, kc, bm, bn), jnp.float32)
 
 
-def _pair_chunk_sums(a_ref, w_ref, d: int, e: int, c0, *, chunk: int,
-                     cb: int, sigma: float, noise) -> jax.Array:
-    """Noisy chunk sums for one (act-plane, weight-plane) pair over the
-    ``cb`` WDM chunks starting at chunk index ``c0`` of one
-    (bm, bk) x (bk, bn) tile. Returns (cb, bm, bn) float32 — exact small
-    integers plus (optionally) the transmission-noise term. Shared by
-    both kernels so the auto-range pass sees exactly the signal the
-    readout pass digitizes; ``noise`` is the pair's full (kc, bm, bn)
-    draw, sliced here so sub-blocking never changes which normal lands on
-    which chunk."""
-    a_t = a_ref[d].astype(jnp.float32)            # (bm, bk)
-    w_t = w_ref[e].astype(jnp.float32)            # (bk, bn)
-    bm, bn = a_t.shape[0], w_t.shape[1]
-    a_t = jax.lax.dynamic_slice_in_dim(a_t, c0 * chunk, cb * chunk, axis=1)
-    w_t = jax.lax.dynamic_slice_in_dim(w_t, c0 * chunk, cb * chunk, axis=0)
-    a_c = a_t.reshape(bm, cb, chunk).transpose(1, 0, 2)   # (cb, bm, chunk)
-    w_c = w_t.reshape(cb, chunk, bn)                      # (cb, chunk, bn)
-    dims = (((2,), (1,)), ((0,), (0,)))
+def _chunk_sum(a_ref, w_ref, d: int, e: int, c: int, *, chunk: int,
+               sigma: float, noise) -> jax.Array:
+    """Noisy photodetector sum of WDM chunk ``c`` for one (act-plane,
+    weight-plane) pair of one (bm, bk) x (bk, bn) tile. Returns (bm, bn)
+    float32 — an exact small integer plus (optionally) the
+    transmission-noise term. Shared by both kernels so the auto-range
+    pass sees exactly the signal the readout pass digitizes.
+
+    ``c`` is a Python int: the chunk's columns are a static slice of the
+    tile (Mosaic lowers no in-kernel dynamic_slice), and its sum is one
+    int8 x int8 -> int32 matmul of contraction ``chunk`` — exact, since
+    nibble products summed over a chunk stay far inside int32."""
+    cols = slice(c * chunk, (c + 1) * chunk)
+    a_c = a_ref[d, :, cols]                       # (bm, chunk) int8
+    w_c = w_ref[e, cols, :]                       # (chunk, bn) int8
+    dims = (((1,), (0,)), ((), ()))
     sums = jax.lax.dot_general(a_c, w_c, dims,
-                               preferred_element_type=jnp.float32)
+                               preferred_element_type=jnp.int32)
+    sums = sums.astype(jnp.float32)
     if sigma > 0.0:
-        noise_blk = jax.lax.dynamic_slice_in_dim(noise, c0, cb, axis=0)
-        prod_sq = jax.lax.dot_general(a_c * a_c, w_c * w_c, dims,
+        a_f, w_f = a_c.astype(jnp.float32), w_c.astype(jnp.float32)
+        prod_sq = jax.lax.dot_general(a_f * a_f, w_f * w_f, dims,
                                       preferred_element_type=jnp.float32)
-        sums = sums + sigma * jnp.sqrt(prod_sq) * noise_blk
+        sums = sums + sigma * jnp.sqrt(prod_sq) * noise[c]
     return sums
 
 
-def _fullscale_kernel(*refs, chunk: int, kc: int, cb: int, pa: int,
-                      pw: int, sigma: float, has_noise: bool):
+def _fullscale_kernel(*refs, chunk: int, kc: int, pa: int, pw: int,
+                      sigma: float, has_noise: bool):
     """Auto-ranging pass: running max |chunk sum| over every plane pair
     and grid step, accumulated into one (SUBLANE, LANE) block (the scalar
-    is broadcast across the block so no width-1 writes are needed). The
-    chunk axis is folded ``cb`` chunks at a time — max is associative, so
-    the blocked fold is bit-identical to a whole-tile reduction."""
+    is broadcast across the block so no width-1 writes are needed). Max
+    is associative, so the per-chunk fold is bit-identical to a
+    whole-tile reduction."""
     if has_noise:
         a_ref, w_ref, seed_ref, o_ref = refs
     else:
@@ -160,19 +148,15 @@ def _fullscale_kernel(*refs, chunk: int, kc: int, cb: int, pa: int,
     for d in range(pa):
         for e in range(pw):
             pair_noise = noise[d * pw + e] if has_noise else None
-
-            def blk(i, cur, d=d, e=e, pair_noise=pair_noise):
-                sums = _pair_chunk_sums(
-                    a_ref, w_ref, d, e, i * cb, chunk=chunk, cb=cb,
-                    sigma=sigma, noise=pair_noise)
-                return jnp.maximum(cur, jnp.max(jnp.abs(sums)))
-
-            tile_max = jax.lax.fori_loop(0, kc // cb, blk, tile_max)
+            for c in range(kc):
+                sums = _chunk_sum(a_ref, w_ref, d, e, c, chunk=chunk,
+                                  sigma=sigma, noise=pair_noise)
+                tile_max = jnp.maximum(tile_max, jnp.max(jnp.abs(sums)))
     o_ref[...] = jnp.maximum(o_ref[...],
                              jnp.full(o_ref.shape, tile_max))
 
 
-def _readout_kernel(*refs, chunk: int, kc: int, cb: int, pa: int, pw: int,
+def _readout_kernel(*refs, chunk: int, kc: int, pa: int, pw: int,
                     sigma: float, has_noise: bool, has_bias: bool,
                     n_k: int):
     """Readout pass: shift-weighted ADC codes accumulated in int32 across
@@ -198,28 +182,22 @@ def _readout_kernel(*refs, chunk: int, kc: int, cb: int, pa: int, pw: int,
     noise = (_tile_noise(seed_ref[0], pa * pw, kc,
                          a_ref.shape[1], w_ref.shape[2])
              if has_noise else None)
+    lsb = lsb_ref[0]
     acc = acc_ref[...]
     for d in range(pa):
         for e in range(pw):
             pair_noise = noise[d * pw + e] if has_noise else None
-
-            def blk(i, cur, d=d, e=e, pair_noise=pair_noise):
-                # live transient is one (cb, bm, bn) sub-block, not the
-                # full (kc, bm, bn) tile — see chunk_transient_bytes
-                sums = _pair_chunk_sums(
-                    a_ref, w_ref, d, e, i * cb, chunk=chunk, cb=cb,
-                    sigma=sigma, noise=pair_noise)
+            pair_codes = jnp.zeros(acc.shape, jnp.int32)
+            for c in range(kc):
+                sums = _chunk_sum(a_ref, w_ref, d, e, c, chunk=chunk,
+                                  sigma=sigma, noise=pair_noise)
                 # shared auto-ranged ADC: |sums| <= full_scale by
                 # construction so codes are in [-half_levels,
                 # half_levels] — no clamp; the digital accumulator sums
                 # shift-weighted codes in int32 (exact — neither K-tile
                 # order nor fast-math can perturb it)
-                codes = jnp.round(sums / lsb_ref[0]).astype(jnp.int32)
-                return cur + jnp.sum(codes, axis=0)
-
-            pair_codes = jax.lax.fori_loop(
-                0, kc // cb, blk,
-                jnp.zeros(acc.shape, jnp.int32))
+                pair_codes = pair_codes + jnp.round(sums / lsb).astype(
+                    jnp.int32)
             acc = acc + pair_codes * (16 ** (d + e))
     acc_ref[...] = acc
 
@@ -259,13 +237,12 @@ def _pad_operands(a_planes, w_planes, a_scale, w_scale, bias, bm, bn, bk):
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "sigma", "bm", "bn", "bk",
-                                    "chunk_block", "interpret"))
+                                    "interpret"))
 def analog_fullscale_pallas(a_planes: jax.Array, w_planes: jax.Array,
                             seed: Optional[jax.Array] = None,
                             *, chunk: int, sigma: float = 0.0,
                             bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                             bk: int = DEFAULT_BK,
-                            chunk_block: int = DEFAULT_CHUNK_BLOCK,
                             interpret: bool = False) -> jax.Array:
     """Auto-ranging pass: the shared ADC full scale.
 
@@ -284,6 +261,7 @@ def analog_fullscale_pallas(a_planes: jax.Array, w_planes: jax.Array,
     pw, k2, n = w_planes.shape
     assert k == k2, f"contraction mismatch {k} vs {k2}"
     has_noise = sigma > 0.0 and seed is not None
+    _check_noise_lowerable(has_noise, interpret)
     bm, bn, bk = analog_tiles(m, k, n, chunk, bm, bn, bk)
     a_planes, w_planes, _, _, _ = _pad_operands(
         a_planes, w_planes, jnp.zeros((m, 1), jnp.float32),
@@ -302,9 +280,8 @@ def analog_fullscale_pallas(a_planes: jax.Array, w_planes: jax.Array,
 
     kc = bk // chunk
     out = pl.pallas_call(
-        functools.partial(_fullscale_kernel, chunk=chunk, kc=kc,
-                          cb=_chunk_block_for(kc, chunk_block),
-                          pa=pa, pw=pw, sigma=sigma if has_noise else 0.0,
+        functools.partial(_fullscale_kernel, chunk=chunk, kc=kc, pa=pa,
+                          pw=pw, sigma=sigma if has_noise else 0.0,
                           has_noise=has_noise),
         grid=(mp // bm, np_ // bn, n_k),
         in_specs=in_specs,
@@ -318,7 +295,7 @@ def analog_fullscale_pallas(a_planes: jax.Array, w_planes: jax.Array,
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "sigma", "bm", "bn", "bk",
-                                    "chunk_block", "interpret"))
+                                    "interpret"))
 def analog_readout_pallas(a_planes: jax.Array, w_planes: jax.Array,
                           a_scale: jax.Array, w_scale: jax.Array,
                           lsb: jax.Array,
@@ -327,7 +304,6 @@ def analog_readout_pallas(a_planes: jax.Array, w_planes: jax.Array,
                           *, chunk: int, sigma: float = 0.0,
                           bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                           bk: int = DEFAULT_BK,
-                          chunk_block: int = DEFAULT_CHUNK_BLOCK,
                           interpret: bool = False) -> jax.Array:
     """Readout pass: fused chunk sums -> noise -> ADC -> integer code
     accumulation -> shift-and-add -> dequant epilogue.
@@ -352,6 +328,7 @@ def analog_readout_pallas(a_planes: jax.Array, w_planes: jax.Array,
     assert a_scale.shape == (m, 1), f"a_scale shape {a_scale.shape}"
     assert w_scale.shape == (1, n), f"w_scale shape {w_scale.shape}"
     has_noise = sigma > 0.0 and seed is not None
+    _check_noise_lowerable(has_noise, interpret)
     has_bias = bias is not None
     bm, bn, bk = analog_tiles(m, k, n, chunk, bm, bn, bk)
     a_planes, w_planes, a_scale, w_scale, bias = _pad_operands(
@@ -381,9 +358,8 @@ def analog_readout_pallas(a_planes: jax.Array, w_planes: jax.Array,
 
     kc = bk // chunk
     out = pl.pallas_call(
-        functools.partial(_readout_kernel, chunk=chunk, kc=kc,
-                          cb=_chunk_block_for(kc, chunk_block),
-                          pa=pa, pw=pw, sigma=sigma if has_noise else 0.0,
+        functools.partial(_readout_kernel, chunk=chunk, kc=kc, pa=pa,
+                          pw=pw, sigma=sigma if has_noise else 0.0,
                           has_noise=has_noise, has_bias=has_bias, n_k=n_k),
         grid=(mp // bm, np_ // bn, n_k),
         in_specs=in_specs,

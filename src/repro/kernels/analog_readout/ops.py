@@ -17,8 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.analog_readout.analog_readout import (
-    DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, DEFAULT_CHUNK_BLOCK,
-    analog_fullscale_pallas, analog_readout_pallas, chunk_transient_bytes)
+    DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, analog_fullscale_pallas,
+    analog_readout_pallas)
 from repro.kernels.analog_readout.ref import (analog_fullscale_ref,
                                               analog_readout_fused_ref,
                                               clamp_fullscale,
@@ -28,7 +28,7 @@ from repro.kernels.runtime import resolve_interpret
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "adc_bits", "sigma", "bm",
-                                    "bn", "bk", "chunk_block", "interpret",
+                                    "bn", "bk", "interpret",
                                     "use_ref"))
 def analog_matmul_fused(a_planes: jax.Array, w_planes: jax.Array,
                         a_scale: jax.Array, w_scale: jax.Array,
@@ -37,7 +37,6 @@ def analog_matmul_fused(a_planes: jax.Array, w_planes: jax.Array,
                         *, chunk: int, adc_bits: int, sigma: float = 0.0,
                         bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                         bk: int = DEFAULT_BK,
-                        chunk_block: int = DEFAULT_CHUNK_BLOCK,
                         interpret: Optional[bool] = None,
                         use_ref: bool = False) -> jax.Array:
     """Nibble planes + scales -> (M, N) float32 through the full analog
@@ -70,8 +69,7 @@ def analog_matmul_fused(a_planes: jax.Array, w_planes: jax.Array,
         a_planes = jnp.pad(a_planes, ((0, 0), (0, 0), (0, pad_c)))
         w_planes = jnp.pad(w_planes, ((0, 0), (0, pad_c), (0, 0)))
     kw = dict(chunk=chunk, sigma=sigma if has_noise else 0.0, bm=bm,
-              bn=bn, bk=bk, chunk_block=chunk_block,
-              interpret=resolve_interpret(interpret))
+              bn=bn, bk=bk, interpret=resolve_interpret(interpret))
     fs = analog_fullscale_pallas(a_planes, w_planes, seed, **kw)
     lsb = clamp_fullscale(fs) * inv_half_levels(adc_bits)
     return analog_readout_pallas(a_planes, w_planes, a_scale, w_scale,
@@ -80,4 +78,4 @@ def analog_matmul_fused(a_planes: jax.Array, w_planes: jax.Array,
 
 __all__ = ["analog_matmul_fused", "analog_fullscale_pallas",
            "analog_readout_pallas", "analog_fullscale_ref",
-           "analog_readout_fused_ref", "chunk_transient_bytes"]
+           "analog_readout_fused_ref"]

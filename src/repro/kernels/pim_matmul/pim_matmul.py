@@ -72,6 +72,20 @@ def kernel_tiles(m: int, k: int, n: int, bm: int = DEFAULT_BM,
     return min(bm, m), min(bn, n), min(bk, k)
 
 
+def _plane_pairs_acc(a_ref, w_ref, acc, pa: int, pw: int):
+    """Add every (act-plane, weight-plane) product of this K step to
+    ``acc``. Each pair is one int8 x int8 -> int32 MXU matmul (nibbles lie
+    in [-8, 7], so the int32 accumulator is exact) plus a static shift;
+    plane pairs are unrolled."""
+    for d in range(pa):
+        for e in range(pw):
+            partial = jax.lax.dot_general(
+                a_ref[d], w_ref[e], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)
+            acc = acc + partial * (16 ** (d + e))
+    return acc
+
+
 def _pim_matmul_kernel(a_ref, w_ref, o_ref, acc_ref, *, n_k: int,
                        pa: int, pw: int):
     """One (m, n, k) grid step.
@@ -87,17 +101,7 @@ def _pim_matmul_kernel(a_ref, w_ref, o_ref, acc_ref, *, n_k: int,
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc = acc_ref[...]
-    # Unrolled plane pairs: each is one MXU int matmul + a static shift.
-    for d in range(pa):
-        a_pl = a_ref[d].astype(jnp.int32)
-        for e in range(pw):
-            w_pl = w_ref[e].astype(jnp.int32)
-            partial = jax.lax.dot_general(
-                a_pl, w_pl, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-            acc = acc + partial * (16 ** (d + e))
-    acc_ref[...] = acc
+    acc_ref[...] = _plane_pairs_acc(a_ref, w_ref, acc_ref[...], pa, pw)
 
     @pl.when(k_step == n_k - 1)
     def _write_out():
@@ -189,16 +193,7 @@ def _pim_matmul_fused_kernel(a_ref, w_ref, as_ref, ws_ref, *rest, n_k: int,
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc = acc_ref[...]
-    for d in range(pa):
-        a_pl = a_ref[d].astype(jnp.int32)
-        for e in range(pw):
-            w_pl = w_ref[e].astype(jnp.int32)
-            partial = jax.lax.dot_general(
-                a_pl, w_pl, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-            acc = acc + partial * (16 ** (d + e))
-    acc_ref[...] = acc
+    acc_ref[...] = _plane_pairs_acc(a_ref, w_ref, acc_ref[...], pa, pw)
 
     @pl.when(k_step == n_k - 1)
     def _write_out():

@@ -55,7 +55,10 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
+import os
+import pathlib
 import time
 import warnings
 from typing import Any, Dict, List, Optional, Sequence
@@ -100,8 +103,14 @@ def plan_params_for_pim(params, cfg: PimConfig):
     Weights the planned path does not cover (SSM projections, embedding
     tables — any ``PIM_WEIGHT_SUFFIXES`` leaf without an engine route)
     keep quantize-dequantize fake-quantization so every substrate still
-    models their cell-density programming."""
+    models their cell-density programming.
+
+    Each leaf is programmed by one jitted program: eager op-by-op
+    execution would compile every primitive separately and hold each
+    full-size f32 temporary (quantize, STE, nibble split) in device
+    memory at once."""
     sub = engine.get_substrate(cfg.resolved_substrate)
+    fake_q = jax.jit(fake_quantize, static_argnames=("bits", "axis"))
 
     def _cfg_for(keys):
         # with ABFT verification on, each planned weight gets its tree
@@ -111,13 +120,19 @@ def plan_params_for_pim(params, cfg: PimConfig):
             return cfg
         return dataclasses.replace(cfg, abft_tag="/".join(keys))
 
-    def plan_stack(v, keys):
-        c = _cfg_for(keys)
+    @functools.partial(jax.jit, static_argnums=1)
+    def _program_stack(v, c):
         return jax.vmap(lambda w: sub.program(w, c))(v)
 
-    def plan_expert_stack(v, keys):
-        c = _cfg_for(keys)
+    @functools.partial(jax.jit, static_argnums=1)
+    def _program_expert_stack(v, c):
         return jax.vmap(lambda w: sub.program_experts(w, c))(v)
+
+    def plan_stack(v, keys):
+        return _program_stack(v, _cfg_for(keys))
+
+    def plan_expert_stack(v, keys):
+        return _program_expert_stack(v, _cfg_for(keys))
 
     def _will_plan(keys, name, x) -> bool:
         if not any(k in _PLANNED_BLOCKS for k in keys):
@@ -141,7 +156,7 @@ def plan_params_for_pim(params, cfg: PimConfig):
                 out[k] = (plan_expert_stack(v, keys + [k]) if v.ndim == 4
                           else plan_stack(v, keys + [k]))
             elif _quantizable(k, v):
-                out[k] = fake_quantize(v, cfg.weight_bits, axis=(v.ndim - 2,))
+                out[k] = fake_q(v, bits=cfg.weight_bits, axis=(v.ndim - 2,))
             else:
                 out[k] = v
         return out
@@ -152,7 +167,7 @@ def plan_params_for_pim(params, cfg: PimConfig):
         if _will_plan(keys, name, x):
             return x   # replaced by a plan below; don't quantize twice
         if _quantizable(name, x):
-            return fake_quantize(x, cfg.weight_bits, axis=(x.ndim - 2,))
+            return fake_q(x, bits=cfg.weight_bits, axis=(x.ndim - 2,))
         return x
 
     out = dict(jax.tree_util.tree_map_with_path(q, params))
@@ -317,50 +332,62 @@ def _resolve_substrate(pim_substrate: Optional[str],
     return pim_substrate or "exact-pallas"
 
 
-def enable_compile_cache(cache_dir: str) -> None:
-    """Point jax's persistent compilation cache at ``cache_dir`` so serve
+# Where the persistent compilation cache lives when the environment does
+# not name one: a fixed directory inside the checkout (the path is part of
+# the cache key, so a directory that moves never hits).
+DEFAULT_COMPILE_CACHE = (pathlib.Path(__file__).resolve().parents[3]
+                         / ".jax_cache")
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point, so
     restarts reuse compiled executables instead of re-lowering every step
-    function. The size/compile-time floors are dropped to zero: serving
-    compiles few, hot programs, and on a restart even a small prefill
-    executable is worth a disk hit."""
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    function. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and no other directory is configured here; otherwise the cache
+    goes to :data:`DEFAULT_COMPILE_CACHE`. The size/compile-time floors
+    are dropped to zero: serving compiles few, hot programs. Called by
+    entry points only (serve's ``main`` and ``chip_smoke.py``), never by
+    library code. Returns the cache directory."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(DEFAULT_COMPILE_CACHE)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        # the cache singleton initializes lazily at the first compile; if
-        # anything compiled before this call (imports do), it latched a
-        # no-dir cache and the config updates above are ignored — reset
-        # so the next compile re-initializes against cache_dir
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    except (ImportError, AttributeError):
-        pass   # config flags above are still honored on first compile
+    # the cache latches its directory at the first compile; anything that
+    # compiled during imports would otherwise keep it pointed nowhere
+    compilation_cache.reset_cache()
+    return cache_dir
 
 
 def _setup(arch: str, layers: Optional[int], d_model: Optional[int],
            pim: bool, pim_bits: int, pim_emulate: bool,
            pim_substrate: Optional[str], plan_dir: Optional[str],
            mesh_spec: Optional[str] = None,
-           compile_cache_dir: Optional[str] = None,
            abft: str = "off"):
     """Shared serve bring-up: config reduction, param init, and (with
     ``pim``) weight programming — identical for both serving modes, so
     continuous mode streams past exactly the plans static mode uses.
 
+    ``layers`` alone cuts depth only: every published width and the full
+    vocabulary stay. ``d_model`` selects the smoke reduction instead
+    (small widths, a 512-token vocabulary, ``layers`` or 2 layers).
+
     ``mesh_spec`` ("dp,tp") builds a ("data", "model") device mesh and
     splits the programmed plans over it (:mod:`repro.engine.mesh`):
     column/row tensor-parallel for stacked projections, expert-parallel
     for MoE stacks, with everything else replicated."""
-    if compile_cache_dir:
-        enable_compile_cache(compile_cache_dir)
     mesh = None
     if mesh_spec:
         from repro.launch.mesh import make_serve_mesh
         mesh = make_serve_mesh(mesh_spec)
     cfg = get_config(arch)
-    if layers or d_model:
-        cfg = cfg.reduced(num_layers=layers or 2, d_model=d_model or 64,
+    if d_model:
+        cfg = cfg.reduced(num_layers=layers or 2, d_model=d_model,
                           vocab=min(cfg.vocab_size, 512))
+    elif layers:
+        cfg = cfg.with_depth(layers)
     params = init_lm(cfg, jax.random.PRNGKey(0))
     substrate = _resolve_substrate(pim_substrate, pim_emulate)
     pim_cfg = PimConfig(weight_bits=pim_bits, act_bits=pim_bits,
@@ -398,7 +425,6 @@ def serve(arch: str, batch: int = 2, prompt_len: int = 16, gen: int = 8,
           pim: bool = False, pim_bits: int = 4, pim_emulate: bool = False,
           greedy: bool = True, pim_substrate: Optional[str] = None,
           plan_dir: Optional[str] = None, mesh: Optional[str] = None,
-          compile_cache_dir: Optional[str] = None,
           metrics_json: Optional[str] = None,
           stop_tokens: Sequence[int] = (),
           eos_token: Optional[int] = None) -> Dict[str, Any]:
@@ -417,7 +443,7 @@ def serve(arch: str, batch: int = 2, prompt_len: int = 16, gen: int = 8,
     the same request."""
     cfg, params, substrate, pim_cfg, _ = _setup(
         arch, layers, d_model, pim, pim_bits, pim_emulate, pim_substrate,
-        plan_dir, mesh_spec=mesh, compile_cache_dir=compile_cache_dir)
+        plan_dir, mesh_spec=mesh)
 
     rng = np.random.default_rng(0)
     batch_in: Dict[str, Any] = {
@@ -556,7 +582,6 @@ def serve_continuous(arch: str, num_slots: int = 4, num_requests: int = 16,
                      arrival_rate: float = 0.5,
                      trace_file: Optional[str] = None, seed: int = 0,
                      sync_every: int = 1, mesh: Optional[str] = None,
-                     compile_cache_dir: Optional[str] = None,
                      metrics_json: Optional[str] = None,
                      sanitize: bool = False,
                      stop_tokens: Sequence[int] = (),
@@ -612,8 +637,7 @@ def serve_continuous(arch: str, num_slots: int = 4, num_requests: int = 16,
                          "would go undetected)")
     cfg, params, substrate, pim_cfg, dev_mesh = _setup(
         arch, layers, d_model, pim, pim_bits, pim_emulate, pim_substrate,
-        plan_dir, mesh_spec=mesh, compile_cache_dir=compile_cache_dir,
-        abft=abft)
+        plan_dir, mesh_spec=mesh, abft=abft)
     if trace_file:
         requests = _load_trace(trace_file, cfg.vocab_size, seed)
         if not requests:
@@ -757,8 +781,12 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=8)
-    ap.add_argument("--layers", type=int, default=None)
-    ap.add_argument("--d-model", type=int, default=None)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut depth to this many layers; alone it keeps "
+                         "every published width and the full vocabulary")
+    ap.add_argument("--d-model", type=int, default=None,
+                    help="smoke reduction: shrink every width to this "
+                         "d_model (vocabulary cut to 512)")
     ap.add_argument("--pim", action="store_true")
     ap.add_argument("--pim-bits", type=int, default=4)
     ap.add_argument("--pim-substrate", default=None,
@@ -776,9 +804,6 @@ def main() -> None:
                          "decode slots over the data axis (CPU: force "
                          "devices with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persist jax's compilation cache here so serve "
-                         "restarts skip XLA re-compilation")
     ap.add_argument("--continuous", action="store_true",
                     help="continuous batching: Poisson/trace arrivals "
                          "through the slot scheduler (repro/serving/)")
@@ -854,6 +879,7 @@ def main() -> None:
                          "compile-count sentinel asserting each step "
                          "function compiled exactly once")
     args = ap.parse_args()
+    setup_compile_cache()
     stop_tokens = tuple(
         int(t) for t in args.stop_tokens.split(",") if t.strip()
     ) if args.stop_tokens else ()
@@ -867,7 +893,6 @@ def main() -> None:
             pim_substrate=args.pim_substrate, plan_dir=args.plan_dir,
             arrival_rate=args.arrival_rate, trace_file=args.trace_file,
             seed=args.seed, sync_every=args.sync_every, mesh=args.mesh,
-            compile_cache_dir=args.compile_cache_dir,
             metrics_json=args.metrics_json, sanitize=args.sanitize,
             stop_tokens=stop_tokens, eos_token=args.eos_token,
             prefill_chunk=args.prefill_chunk,
@@ -910,7 +935,6 @@ def main() -> None:
                     args.layers, args.d_model, args.pim, args.pim_bits,
                     args.pim_emulate, pim_substrate=args.pim_substrate,
                     plan_dir=args.plan_dir, mesh=args.mesh,
-                    compile_cache_dir=args.compile_cache_dir,
                     metrics_json=args.metrics_json,
                     stop_tokens=stop_tokens, eos_token=args.eos_token)
         print(f"[serve] prefill {res['prefill_s']*1e3:.1f}ms, "

@@ -27,7 +27,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_context
@@ -218,7 +218,7 @@ def moe_apply(p: Params, x: jax.Array, experts_per_token: int,
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=tok_spec,
-            check_rep=False,
+            check_vma=False,
         )(x2, probs, ids, p["wi_edf"], p["wg_edf"], p["wo_efd"])
     else:
         out2 = _moe_local(x2, probs, ids, p["wi_edf"], p["wg_edf"],

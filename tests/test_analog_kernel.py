@@ -220,6 +220,20 @@ def test_noise_statistics_match_jnp_reference():
     assert abs(float(dev_a.mean())) < 0.1 * std_a
 
 
+def test_noise_path_refuses_compiled_lowering():
+    """The noise path has no Mosaic lowering: compiled, both passes
+    refuse it by name instead of falling back to the interpreter."""
+    a, w, a_s, w_s = _planes(jax.random.PRNGKey(0), 1, 1, 8, 64, 16)
+    seed = jnp.int32(1)
+    with pytest.raises(NotImplementedError, match="noise path"):
+        analog_fullscale_pallas(a, w, seed, chunk=8, sigma=0.05,
+                                interpret=False)
+    with pytest.raises(NotImplementedError, match="noise path"):
+        analog_ops.analog_readout_pallas(a, w, a_s, w_s, jnp.float32(1.0),
+                                         seed, chunk=8, sigma=0.05,
+                                         interpret=False)
+
+
 # ---------------------------------------------------------------------------
 # registry + persistence
 # ---------------------------------------------------------------------------

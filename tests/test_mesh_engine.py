@@ -136,6 +136,41 @@ _SCHED_SCRIPT = _PREAMBLE + textwrap.dedent("""
     print("sched_mesh_ok", len(t0))
 """)
 
+_DATA_ONLY_SCRIPT = _PREAMBLE + textwrap.dedent("""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_serve_mesh
+    from repro.launch.serve import serve_continuous
+    mesh = make_serve_mesh("4,1")
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 96))
+    w = jax.random.normal(jax.random.PRNGKey(1), (96, 64))
+    f = jax.jit(lambda a, p: engine.matmul(a, p))
+    cfg = engine.PimConfig()
+    # a size-1 split axis still stamps the plan, so the Pallas kernel
+    # runs under shard_map with the batch over "data"
+    plan = engine.program(w, cfg, mesh=mesh, spec="row")
+    assert plan.shard is not None and plan.shard.kind == "col"
+    xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
+    assert np.array_equal(np.asarray(f(x, engine.program(w, cfg))),
+                          np.asarray(f(xs, plan))), "data-only matmul"
+    we = jax.random.normal(jax.random.PRNGKey(3), (4, 96, 64))
+    experts = engine.program(we, cfg, kind="experts", mesh=mesh)
+    assert experts.shard is not None and experts.shard.kind == "expert"
+    analog = engine.program(w, engine.PimConfig(substrate="analog"),
+                            mesh=mesh)
+    assert analog.shard is None
+    kw = dict(num_slots=4, num_requests=6, prompt_len=16, gen=8, layers=2,
+              d_model=64, pim=True, arrival_rate=0.5, seed=0)
+    t0 = {r["id"]: r["tokens"]
+          for r in serve_continuous("qwen2.5-3b", **kw)["requests"]}
+    t1 = {r["id"]: r["tokens"]
+          for r in serve_continuous("qwen2.5-3b", mesh="4,1",
+                                    **kw)["requests"]}
+    assert t0.keys() == t1.keys()
+    for k in t0:
+        assert np.array_equal(t0[k], t1[k]), f"request {k} tokens differ"
+    print("data_only_ok")
+""")
+
 
 def _run(script: str, timeout: int = 900):
     env = dict(os.environ)
@@ -170,3 +205,10 @@ def test_sharded_continuous_scheduler_token_equality():
     proc = _run(_SCHED_SCRIPT)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "sched_mesh_ok" in proc.stdout
+
+
+@pytest.mark.slow
+def test_data_only_mesh_stamps_plans():
+    proc = _run(_DATA_ONLY_SCRIPT)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "data_only_ok" in proc.stdout

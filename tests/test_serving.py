@@ -282,6 +282,35 @@ def test_serve_continuous_driver(tmp_path):
     assert all(isinstance(r["tokens"], list) for r in data["requests"])
 
 
+@pytest.mark.parametrize("d_model", [None, 32], ids=["depth-only", "smoke"])
+def test_setup_layers_cut(monkeypatch, d_model):
+    """``layers`` alone cuts depth and keeps every published width and
+    the full vocabulary; with ``d_model`` it is the smoke reduction. The
+    config is read before init, so no full-width weights are built."""
+    from repro.launch import serve as serve_mod
+
+    class Built(Exception):
+        pass
+
+    def capture(cfg, key):
+        raise Built(cfg)
+
+    monkeypatch.setattr(serve_mod, "init_lm", capture)
+    with pytest.raises(Built) as exc:
+        serve_mod._setup("qwen2.5-3b", 3, d_model, pim=False, pim_bits=4,
+                         pim_emulate=False, pim_substrate=None,
+                         plan_dir=None)
+    cfg, full = exc.value.args[0], get_config("qwen2.5-3b")
+    assert cfg.num_layers == 3
+    if d_model is None:
+        assert cfg == full.with_depth(3)
+        assert (cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                cfg.d_ff, cfg.vocab_size) == (2048, 16, 2, 128, 11008,
+                                              151936)
+    else:
+        assert (cfg.d_model, cfg.vocab_size) == (32, 512)
+
+
 def test_serve_static_metrics_json(tmp_path):
     from repro.launch.serve import serve
     path = tmp_path / "static.json"
