@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import argparse
 import time
-from typing import List, Tuple
+from typing import Dict, Hashable, List, Tuple
 
 import jax
 import numpy as np
@@ -101,16 +101,24 @@ def phase_bench(cfg, params) -> List[Row]:
 # chunked vs unchunked prefill: token-stall + wall TTFT on a mixed burst
 # ---------------------------------------------------------------------------
 class _WallMarks:
-    """Callback recording a wall timestamp per delivered token."""
+    """Callback recording a wall timestamp per delivered token, and each
+    request's first and last token times from the callback's creation
+    (just before the run)."""
 
     def __init__(self):
+        self.t0 = time.perf_counter()
         self.marks: List[float] = []
+        self.first: Dict[Hashable, float] = {}
+        self.last: Dict[Hashable, float] = {}
 
     def on_admit(self, request_id, slot, step):
         pass
 
     def on_token(self, request_id, token, index):
-        self.marks.append(time.perf_counter())
+        t = time.perf_counter()
+        self.marks.append(t)
+        self.first.setdefault(request_id, t - self.t0)
+        self.last[request_id] = t - self.t0
 
     def on_finish(self, completion):
         pass
@@ -147,8 +155,8 @@ def chunked_prefill_bench(cfg, params) -> List[Row]:
         sched.run(reqs)                      # warm second-call paths
         cb = _WallMarks()
         res = sched.run(reqs, callbacks=cb)
-        ttfts = [c.first_token_wall_s * 1e3 for c in res.completions]
-        fins = [c.finish_wall_s * 1e3 for c in res.completions]
+        ttfts = [t * 1e3 for t in cb.first.values()]
+        fins = [t * 1e3 for t in cb.last.values()]
         rows += [
             (f"engine_chunked.{label}.max_token_stall_ms",
              cb.max_gap_ms(), "longest wall gap in token delivery "
@@ -193,10 +201,11 @@ def prefix_cache_bench(cfg, params) -> List[Row]:
             max_len=m + SHORT_PROMPT + 8, prefill_chunk=CHUNK,
             prefix_cache=16)
         sched.warmup()
-        res = sched.run(reqs)
+        cb = _WallMarks()
+        res = sched.run(reqs, callbacks=cb)
         stats = res.metrics["prefix_cache"]
         total = stats["hits"] + stats["misses"]
-        ttfts = [c.first_token_wall_s * 1e3 for c in res.completions]
+        ttfts = [t * 1e3 for t in cb.first.values()]
         tag = f"engine_prefix.share{int(frac * 100):03d}"
         rows += [
             (f"{tag}.hit_rate", stats["hits"] / total if total else 0.0,

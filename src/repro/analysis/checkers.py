@@ -483,16 +483,28 @@ class PytreeChecker(Checker):
 
 # ---------------------------------------------------------------------------
 class PallasTileChecker(Checker):
-    """RPR401/RPR402: BlockSpec register-tile alignment and interpret
-    defaults in library code."""
+    """RPR401/RPR402/RPR403: BlockSpec register-tile alignment,
+    interpret defaults in library code, and a name on every kernel."""
 
     name = "pallas-tile"
-    rules = ("RPR401", "RPR402")
+    rules = ("RPR401", "RPR402", "RPR403")
 
     def check(self, project: Project, module: ModuleInfo
               ) -> Iterable[Finding]:
         yield from self._block_specs(module)
         yield from self._interpret_defaults(module)
+        yield from self._kernel_names(module)
+
+    def _kernel_names(self, module: ModuleInfo) -> Iterable[Finding]:
+        for node in ast.walk(module.tree):
+            if isinstance(node, ast.Call) and \
+                    (attr_chain(node.func) or [""])[-1] == "pallas_call" \
+                    and not any(k.arg == "name" for k in node.keywords):
+                yield Finding(
+                    "RPR403", module.path, node.lineno, node.col_offset,
+                    "pallas_call without name=: a device trace names the "
+                    "kernel after its jitted wrapper, which a refactor "
+                    "renames; pass name=<kernel family>")
 
     def _block_specs(self, module: ModuleInfo) -> Iterable[Finding]:
         for node in ast.walk(module.tree):

@@ -46,6 +46,8 @@ RULES: Dict[str, str] = {
               "tile (compiled Mosaic wants lane-aligned operands)",
     "RPR402": "interpret= defaulted to True in library code (real TPUs "
               "would silently run the Pallas interpreter)",
+    "RPR403": "pallas_call without name= (a trace reduction finds a "
+              "kernel by its name)",
     "RPR501": "deprecated PimConfig alias (use_pallas / analog); use "
               "substrate= registry keys",
 }

@@ -55,9 +55,10 @@ directly: ``engine.program(w, cfg)`` once, ``engine.matmul(x, plan)`` many.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import warnings
-from typing import Optional, Union
+from typing import Callable, List, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +73,19 @@ EXACT_JNP = "exact-jnp"
 ANALOG = "analog"
 ANALOG_PALLAS = "analog-pallas"
 EMULATE = "emulate"
+
+# ``jax.named_scope`` names the CNN executor and the exact-pallas route
+# put on their ops. A profiler's device events carry no metadata, so a
+# trace reduction attributes an op to these through the compiled
+# program's ``op_name`` of the instruction (``compiled.as_text()``). None
+# contains "pim_matmul" or "forward", which trace readers match by name.
+SCOPE_IM2COL = "im2col"          # SAME pad, strided slices, concatenate
+SCOPE_POOL = "pool"              # stage bridge, global average pool
+SCOPE_ACT_QUANT = "act_quant"    # per-row scale, round, planes, K pad
+SCOPE_KERNEL = "kernel"          # the pallas_call, its tile pads, slice
+SCOPE_RESIDUAL = "residual"      # a block's add and ReLU
+SCOPES = (SCOPE_IM2COL, SCOPE_POOL, SCOPE_ACT_QUANT, SCOPE_KERNEL,
+          SCOPE_RESIDUAL)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -526,6 +540,58 @@ def _pad_bias(bias: Optional[jax.Array], plan: DensePlan
                    ((0, 0), (0, pad_n)))
 
 
+@dataclasses.dataclass(frozen=True)
+class KernelCall:
+    """One ``pim_matmul`` call as traced: where it was, its logical
+    (M, K, N) and the tile-padded (Mp, Kp, Np) the kernel issues, and
+    its activation and weight plane counts."""
+    scope: str                   # name stack at the call, "s0b0c1/kernel"
+    m: int
+    k: int
+    n: int
+    mp: int
+    kp: int
+    np: int
+    pa: int
+    pw: int
+
+
+_CALL_LOG: contextvars.ContextVar[Optional[List[KernelCall]]] = \
+    contextvars.ContextVar("kernel_call_log", default=None)
+
+
+def kernel_calls(fn: Callable, *args) -> List[KernelCall]:
+    """Trace ``fn(*args)`` for shapes only (nothing is compiled or run)
+    and return the exact-pallas kernel calls it makes, in order.
+
+    ``args`` may be arrays or ``jax.ShapeDtypeStruct``. JAX reuses a
+    function's earlier trace at the same shapes, which would skip the
+    calls: a fresh closure is traced each time, and ``fn`` must be the
+    plain function, not a ``jax.jit`` of it (``jitted.__wrapped__``)."""
+    log: List[KernelCall] = []
+    token = _CALL_LOG.set(log)
+    try:
+        jax.eval_shape(lambda *a: fn(*a), *args)
+    finally:
+        _CALL_LOG.reset(token)
+    return log
+
+
+def _log_kernel_call(m: int, pa: int, plan: DensePlan) -> None:
+    log = _CALL_LOG.get()
+    if log is None:
+        return
+    from jax._src import source_info_util
+    from repro.kernels.pim_matmul.pim_matmul import kernel_tiles
+    pw, kp, np_ = plan.planes.shape
+    bm, bn, bk = kernel_tiles(m, kp, np_)
+    up = lambda v, b: -(-v // b) * b
+    log.append(KernelCall(
+        scope=str(source_info_util.current_name_stack()), m=m, k=plan.k,
+        n=plan.n, mp=up(m, bm), kp=up(kp, bk), np=up(np_, bn), pa=pa,
+        pw=pw))
+
+
 def exact_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
                           bias: Optional[jax.Array] = None,
                           verify: bool = False) -> jax.Array:
@@ -546,28 +612,31 @@ def exact_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
     row-sum rides the accumulator tile already in VMEM."""
     from repro.kernels.pim_matmul import ops as pim_ops
     from repro.kernels.runtime import resolve_interpret
-    a_q, a_planes = _quantize_activations(x2, cfg)
-    ap = _pad_act_planes(a_planes, plan)
-    if verify and resolve_interpret(cfg.interpret):
-        acc = pim_ops.pim_matmul_int(ap, plan.planes,
-                                     interpret=cfg.interpret)
-        _abft_report_exact(acc.sum(axis=1, dtype=jnp.int32), a_q.values,
-                           plan, cfg)
-        out = acc.astype(jnp.float32) * a_q.scale * plan.padded_scale
-        pb = _pad_bias(bias, plan)
-        if pb is not None:
-            out = out + pb
-        return out[:, :plan.n]
-    res = pim_ops.pim_matmul_fused(ap, plan.planes, a_q.scale,
-                                   plan.padded_scale,
-                                   bias=_pad_bias(bias, plan),
-                                   interpret=cfg.interpret,
-                                   want_rowsum=verify)
-    if verify:
-        out, rowsum = res
-        _abft_report_exact(rowsum, a_q.values, plan, cfg)
-        return out[:, :plan.n]
-    return res[:, :plan.n]
+    with jax.named_scope(SCOPE_ACT_QUANT):
+        a_q, a_planes = _quantize_activations(x2, cfg)
+        ap = _pad_act_planes(a_planes, plan)
+    with jax.named_scope(SCOPE_KERNEL):
+        _log_kernel_call(x2.shape[0], ap.shape[0], plan)
+        if verify and resolve_interpret(cfg.interpret):
+            acc = pim_ops.pim_matmul_int(ap, plan.planes,
+                                         interpret=cfg.interpret)
+            _abft_report_exact(acc.sum(axis=1, dtype=jnp.int32), a_q.values,
+                               plan, cfg)
+            out = acc.astype(jnp.float32) * a_q.scale * plan.padded_scale
+            pb = _pad_bias(bias, plan)
+            if pb is not None:
+                out = out + pb
+            return out[:, :plan.n]
+        res = pim_ops.pim_matmul_fused(ap, plan.planes, a_q.scale,
+                                       plan.padded_scale,
+                                       bias=_pad_bias(bias, plan),
+                                       interpret=cfg.interpret,
+                                       want_rowsum=verify)
+        if verify:
+            out, rowsum = res
+            _abft_report_exact(rowsum, a_q.values, plan, cfg)
+            return out[:, :plan.n]
+        return res[:, :plan.n]
 
 
 # ---------------------------------------------------------------------------

@@ -289,6 +289,7 @@ def analog_fullscale_pallas(a_planes: jax.Array, w_planes: jax.Array,
         out_specs=pl.BlockSpec((SUBLANE, LANE), lambda i, j, s: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((SUBLANE, LANE), jnp.float32),
         interpret=interpret,
+        name="analog_readout_fullscale",
     )(*inputs)
     return out[0, 0]
 
@@ -368,5 +369,6 @@ def analog_readout_pallas(a_planes: jax.Array, w_planes: jax.Array,
         # shift-weighted ADC-code accumulator, persistent across the K axis
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="analog_readout_adc",
     )(*inputs)
     return out[:m, :n]

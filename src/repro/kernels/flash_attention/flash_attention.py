@@ -129,6 +129,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((rep, bq), jnp.float32),      # running denominator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qz, kz, vz)
 
     out = out.reshape(b, kv, rep, s, d)

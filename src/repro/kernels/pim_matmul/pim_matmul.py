@@ -149,6 +149,7 @@ def pim_matmul_pallas(a_planes: jax.Array, w_planes: jax.Array,
         # int32 accumulator tile, persistent across the sequential K axis
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="pim_matmul_int",
     )(a_planes, w_planes)
     return out[:m, :n]
 
@@ -320,6 +321,7 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="pim_matmul_fused",
     )(*inputs)
     if want_rowsum:
         out, partials = out

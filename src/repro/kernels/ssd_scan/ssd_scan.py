@@ -96,6 +96,7 @@ def ssd_scan_pallas(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x.astype(jnp.float32), a.astype(jnp.float32), b.astype(jnp.float32),
       c.astype(jnp.float32))
     return y, s_fin

@@ -750,8 +750,7 @@ def serve_continuous(arch: str, num_slots: int = 4, num_requests: int = 16,
         {"id": c.request_id, "prompt_len": int(c.prompt.shape[0]),
          "tokens": c.tokens, "arrival_step": c.arrival_step,
          "ttft_steps": c.ttft_steps, "latency_steps": c.latency_steps,
-         "stop_reason": c.stop_reason,
-         "first_token_wall_s": c.first_token_wall_s}
+         "stop_reason": c.stop_reason}
         for c in run.completions]
     if pim:
         result["pim_substrate"] = substrate

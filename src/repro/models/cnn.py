@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro import engine
-from repro.core.pim import PimConfig
+from repro.core.pim import (SCOPE_IM2COL, SCOPE_POOL, SCOPE_RESIDUAL,
+                            PimConfig)
 from repro.core.workloads import ConvSpec, DenseSpec, LayerSpec
 from repro.quant.quantize import fake_quantize
 
@@ -51,19 +52,22 @@ def _im2col(x: jax.Array, spec: ConvSpec) -> jax.Array:
     """x: (B, H, W, C) -> patches (B, oh, ow, kh*kw*C), SAME padding."""
     kh, kw, s = spec.kh, spec.kw, spec.stride
     ph, pw = (kh - 1) // 2, (kw - 1) // 2
-    x = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw), (0, 0)))
-    cols = []
-    oh, ow = spec.out_h, spec.out_w
-    for i in range(kh):
-        for j in range(kw):
-            cols.append(x[:, i:i + oh * s:s, j:j + ow * s:s, :])
-    return jnp.concatenate(cols, axis=-1)
+    with jax.named_scope(SCOPE_IM2COL):
+        x = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw),
+                        (0, 0)))
+        cols = []
+        oh, ow = spec.out_h, spec.out_w
+        for i in range(kh):
+            for j in range(kw):
+                cols.append(x[:, i:i + oh * s:s, j:j + ow * s:s, :])
+        return jnp.concatenate(cols, axis=-1)
 
 
 def _maxpool(x: jax.Array, factor: int) -> jax.Array:
-    return jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, factor, factor, 1),
-        (1, factor, factor, 1), "VALID")
+    with jax.named_scope(SCOPE_POOL):
+        return jax.lax.reduce_window(
+            x, -jnp.inf, jax.lax.max, (1, factor, factor, 1),
+            (1, factor, factor, 1), "VALID")
 
 
 class _Executor:
@@ -116,39 +120,42 @@ class _Executor:
 
     def conv(self, spec: ConvSpec, x: jax.Array, relu: bool = True
              ) -> jax.Array:
-        if x.shape[1] > spec.in_h:                 # stage pooling bridge
-            x = _maxpool(x, x.shape[1] // spec.in_h)
-        p = self.params[spec.name]
-        if spec.groups == 1:
-            cols = _im2col(x, spec)
-            y = self.matmul(cols, p["w"].reshape(-1, spec.out_c), (0,),
-                            spec.name, bias=p["b"])
-        else:                                      # depthwise
-            cols = _im2col(x, spec)
-            b, oh, ow, _ = cols.shape
-            cols = cols.reshape(b, oh, ow, spec.kh * spec.kw, spec.in_c)
-            w = p["w"].reshape(spec.kh * spec.kw, spec.in_c)
-            if self.quant_bits:
-                w = fake_quantize(w, self.quant_bits, axis=(0,))
-            if self.pim is not None:
-                # per-channel programmed plan through the bit-sliced engine
-                y = engine.matmul(cols,
-                                  self._plan(spec.name, w, depthwise=True),
-                                  cfg=self.pim)
-            else:
-                y = jnp.einsum("bhwkc,kc->bhwc", cols, w)
-            y = y + p["b"]
-        return jax.nn.relu(y) if relu else y
+        with jax.named_scope(spec.name):
+            if x.shape[1] > spec.in_h:                 # stage pooling bridge
+                x = _maxpool(x, x.shape[1] // spec.in_h)
+            p = self.params[spec.name]
+            if spec.groups == 1:
+                cols = _im2col(x, spec)
+                y = self.matmul(cols, p["w"].reshape(-1, spec.out_c), (0,),
+                                spec.name, bias=p["b"])
+            else:                                      # depthwise
+                cols = _im2col(x, spec)
+                b, oh, ow, _ = cols.shape
+                cols = cols.reshape(b, oh, ow, spec.kh * spec.kw, spec.in_c)
+                w = p["w"].reshape(spec.kh * spec.kw, spec.in_c)
+                if self.quant_bits:
+                    w = fake_quantize(w, self.quant_bits, axis=(0,))
+                if self.pim is not None:
+                    # per-channel programmed plan through the bit-sliced engine
+                    y = engine.matmul(cols,
+                                      self._plan(spec.name, w, depthwise=True),
+                                      cfg=self.pim)
+                else:
+                    y = jnp.einsum("bhwkc,kc->bhwc", cols, w)
+                y = y + p["b"]
+            return jax.nn.relu(y) if relu else y
 
     def dense(self, spec: DenseSpec, x: jax.Array, relu: bool) -> jax.Array:
-        if x.ndim == 4:
-            if spec.in_features == x.shape[1] * x.shape[2] * x.shape[3]:
-                x = x.reshape(x.shape[0], -1)
-            else:
-                x = jnp.mean(x, axis=(1, 2))
-        y = self.matmul(x, self.params[spec.name]["w"], (0,), spec.name,
-                        bias=self.params[spec.name]["b"])
-        return jax.nn.relu(y) if relu else y
+        with jax.named_scope(spec.name):
+            if x.ndim == 4:
+                if spec.in_features == x.shape[1] * x.shape[2] * x.shape[3]:
+                    x = x.reshape(x.shape[0], -1)
+                else:
+                    with jax.named_scope(SCOPE_POOL):
+                        x = jnp.mean(x, axis=(1, 2))
+            y = self.matmul(x, self.params[spec.name]["w"], (0,), spec.name,
+                            bias=self.params[spec.name]["b"])
+            return jax.nn.relu(y) if relu else y
 
 
 def plan_cnn_weights(params: Params, layers: Sequence[LayerSpec],
@@ -220,7 +227,9 @@ def cnn_forward(params: Params, layers: Sequence[LayerSpec], x: jax.Array,
             h = ex.conv(c2s, ex.conv(c1s, x), relu=False)
             shortcut = ex.conv(specs[i + 2], saved, relu=False) if has_ds \
                 else saved
-            x = jax.nn.relu(h + shortcut)
+            with jax.named_scope(c2s.name), \
+                    jax.named_scope(SCOPE_RESIDUAL):
+                x = jax.nn.relu(h + shortcut)
             i += 3 if has_ds else 2
         elif isinstance(spec, ConvSpec):
             last = (i == len(specs) - 1)           # SqueezeNet conv10 head

@@ -284,8 +284,8 @@ class ContinuousScheduler:
         # in-flight (possibly chunked) prefills, FIFO; slot is reserved
         # at task start so concurrent tasks can never oversubscribe
         admitting: List[Tuple[Request, PrefillTask, int]] = []
-        # slot -> (request, admit_step, first_token_wall_s)
-        live: Dict[int, Tuple[Request, float, float]] = {}
+        # slot -> (request, admit_step)
+        live: Dict[int, Tuple[Request, float]] = {}
         completions: List[Completion] = []
         step = 0.0
         decode_steps = prefills = host_syncs = prefill_units = 0
@@ -294,7 +294,7 @@ class ContinuousScheduler:
         t0 = time.time()
 
         def finish(view: SlotView, req: Request, admit_at: float,
-                   first_wall: float, at: float) -> None:
+                   at: float) -> None:
             reason = view.stop_reason or "budget"
             reasons[reason] += 1
             comp = Completion(
@@ -302,9 +302,7 @@ class ContinuousScheduler:
                 prompt=np.asarray(req.tokens, np.int32),
                 tokens=np.asarray(view.tokens, np.int32),
                 arrival_step=req.arrival, admit_step=admit_at,
-                finish_step=at, slot=view.slot, stop_reason=reason,
-                first_token_wall_s=first_wall,
-                finish_wall_s=time.time() - t0)
+                finish_step=at, slot=view.slot, stop_reason=reason)
             completions.append(comp)
             cb.on_finish(comp)
 
@@ -317,9 +315,7 @@ class ContinuousScheduler:
                 prompt=np.asarray(req.tokens, np.int32),
                 tokens=np.zeros((0,), np.int32),
                 arrival_step=req.arrival, admit_step=at,
-                finish_step=at, slot=slot, stop_reason="deadline",
-                first_token_wall_s=0.0,
-                finish_wall_s=time.time() - t0)
+                finish_step=at, slot=slot, stop_reason="deadline")
             completions.append(comp)
             cb.on_finish(comp)
 
@@ -344,12 +340,12 @@ class ContinuousScheduler:
             for slot in [s for s, v in live.items()
                          if v[0].deadline is not None
                          and now >= v[0].deadline]:
-                req, admit_at, first_wall = live.pop(slot)
+                req, admit_at = live.pop(slot)
                 view = state.slots.pop(slot)
                 state.alloc.free(slot)
                 view.done = True
                 view.stop_reason = "deadline"
-                finish(view, req, admit_at, first_wall, now)
+                finish(view, req, admit_at, now)
 
         while pending or ready or admitting or state.slots:
             while pending and pending[0].arrival <= step:
@@ -409,15 +405,14 @@ class ContinuousScheduler:
                         request_id=req.request_id, slot=slot)
                     prefills += 1
                     admit_at = step + 1.0
-                    first_wall = time.time() - t0
                     cb.on_admit(req.request_id, slot, admit_at)
                     cb.on_token(req.request_id, view.tokens[0], 0)
                     if view.done:
                         # budget of one — or the prefill token itself is
                         # a stop token: complete without a decode step
-                        finish(view, req, admit_at, first_wall, admit_at)
+                        finish(view, req, admit_at, admit_at)
                     else:
-                        live[slot] = (req, admit_at, first_wall)
+                        live[slot] = (req, admit_at)
             # --- decode over all occupied slots -------------------------
             # With sync_every > 1, up to a full window of fused decode
             # steps runs on-device between host syncs. The bound keeps
@@ -457,9 +452,8 @@ class ContinuousScheduler:
                 for ev in res.events:
                     cb.on_token(ev.request_id, ev.token, ev.index)
                 for view, i_last in res.finished:
-                    req, admit_at, first_wall = live.pop(view.slot)
-                    finish(view, req, admit_at, first_wall,
-                           step + i_last + 1.0)
+                    req, admit_at = live.pop(view.slot)
+                    finish(view, req, admit_at, step + i_last + 1.0)
                 step += float(res.steps)
             else:
                 step += 1.0
@@ -508,7 +502,4 @@ class ContinuousScheduler:
         for name, vals in (("ttft_steps", ttfts), ("latency_steps", lats)):
             for pk, pv in _percentiles(vals).items():
                 metrics[f"{name}_{pk}"] = pv
-        for pk, pv in _percentiles(
-                [c.first_token_wall_s for c in completions]).items():
-            metrics[f"first_token_wall_s_{pk}"] = pv
         return RunResult(completions=completions, metrics=metrics)

@@ -33,12 +33,6 @@ class Completion:
     # the request never won a slot). For token stops, the stop token
     # itself is the last entry of ``tokens``; nothing is emitted after.
     stop_reason: str = "budget"
-    # wall-clock marks relative to the run start (seconds). The virtual
-    # clock stays the unit of latency *accounting*; these feed the
-    # decode microbenchmark's chunked-vs-unchunked TTFT comparison,
-    # which is about real prefill stalls, not scheduling policy.
-    first_token_wall_s: float = 0.0
-    finish_wall_s: float = 0.0
 
     @property
     def ttft_steps(self) -> float:

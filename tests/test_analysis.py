@@ -179,6 +179,17 @@ def test_rpr402_interpret_default_true():
     assert _hits(f) == [("RPR402", 1)]
 
 
+def test_rpr403_pallas_call_without_name():
+    f = _lint("""\
+    from jax.experimental import pallas as pl
+
+    def run(x):
+        a = pl.pallas_call(kernel, out_shape=x)(x)
+        return pl.pallas_call(kernel, out_shape=x, name="k")(a)
+    """, assume_hot=False)
+    assert _hits(f) == [("RPR403", 4)]
+
+
 def test_rpr501_deprecated_aliases():
     f = _lint("""\
     def configure(cfg):

@@ -122,3 +122,43 @@ def test_data_only_mesh_matmul_compiles(topo):
     text = jax.jit(lambda a, p: engine.matmul(a, p)).lower(
         x, plan).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip):
+    """The benchmark cell's forward at its rehearsal sizes: every Mosaic
+    kernel's instruction carries its layer's ``kernel`` scope in the
+    compiled text, which is how a trace reduction finds the kernel's own
+    device events (the events carry no metadata)."""
+    import dataclasses
+    import pathlib
+    import sys
+    bench = pathlib.Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from harness import cells, runner, scopes
+
+    from repro.core import pim
+    from repro.models.cnn import cnn_forward, init_cnn, plan_cnn_weights
+
+    cell = cells.find("resnet18-cifar100.batch1024")
+    cfg, mix = runner.effective(cell, True)
+    drv = cells.driver(cell)
+    layers = drv.layer_specs(cfg)
+    pcfg = dataclasses.replace(drv.pim_config(cfg), interpret=False)
+    place = lambda t: jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), t)
+    params = jax.eval_shape(lambda: init_cnn(layers, jax.random.PRNGKey(0)))
+    plans = jax.eval_shape(lambda p: plan_cnn_weights(p, layers, pcfg),
+                           params)
+    hw = cfg["image_size"]
+    x = jax.ShapeDtypeStruct((mix["batch"], hw, hw, 3), jnp.float32)
+    text = jax.jit(lambda p, pl, x: cnn_forward(
+        p, layers, x, pim=pcfg, plans=pl)).lower(
+            place(params), place(plans), place(x)).compile().as_text()
+    names = scopes.op_names(text)
+    kernels = [scopes.instruction(line) for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == len(layers)
+    known = pim.SCOPES + tuple(spec.name for spec in layers)
+    assert [scopes.innermost(names.get(k), known) for k in kernels] == \
+        [pim.SCOPE_KERNEL] * len(layers)
