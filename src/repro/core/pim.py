@@ -80,8 +80,11 @@ EMULATE = "emulate"
 # program's ``op_name`` of the instruction (``compiled.as_text()``). None
 # contains "pim_matmul" or "forward", which trace readers match by name.
 SCOPE_IM2COL = "im2col"          # SAME pad, strided slices, concatenate
+                                 # (dense conv on exact-pallas: the round
+                                 # and planes of each tap too)
 SCOPE_POOL = "pool"              # stage bridge, global average pool
 SCOPE_ACT_QUANT = "act_quant"    # per-row scale, round, planes, K pad
+                                 # (dense conv on exact-pallas: the scale)
 SCOPE_KERNEL = "kernel"          # the pallas_call, its tile pads, slice
 SCOPE_RESIDUAL = "residual"      # a block's add and ReLU
 SCOPES = (SCOPE_IM2COL, SCOPE_POOL, SCOPE_ACT_QUANT, SCOPE_KERNEL,
@@ -595,12 +598,31 @@ def _log_kernel_call(m: int, pa: int, plan: DensePlan) -> None:
 def exact_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
                           bias: Optional[jax.Array] = None,
                           verify: bool = False) -> jax.Array:
-    """``exact-pallas`` substrate: the Pallas kernel with the fused dequant
-    epilogue (per-row act-scale × per-col weight-scale + optional bias on
-    the int32 accumulator tile in VMEM). With ``verify`` the kernel also
+    """``exact-pallas`` substrate: per-row activation quantization, then
+    the Pallas kernel with the fused dequant epilogue
+    (:func:`exact_pallas_planes_matmul2d`)."""
+    with jax.named_scope(SCOPE_ACT_QUANT):
+        a_q, a_planes = _quantize_activations(x2, cfg)
+        ap = _pad_act_planes(a_planes, plan)
+    return exact_pallas_planes_matmul2d(ap, a_q.scale, plan, cfg, bias,
+                                        verify=verify, a_values=a_q.values)
+
+
+def exact_pallas_planes_matmul2d(ap: jax.Array, a_scale: jax.Array,
+                                 plan: DensePlan, cfg: PimConfig,
+                                 bias: Optional[jax.Array] = None,
+                                 verify: bool = False,
+                                 a_values: Optional[jax.Array] = None
+                                 ) -> jax.Array:
+    """The kernel half of ``exact-pallas``, for activations quantized
+    already: ``ap`` (Pa, M, Kp) int8 planes padded to the plan's K,
+    ``a_scale`` (M, 1) f32 per-row scales. The kernel's fused epilogue
+    applies per-row act-scale × per-col weight-scale + optional bias on
+    the int32 accumulator tile in VMEM. With ``verify`` the kernel also
     returns the int32 accumulator row-sums from the epilogue for the ABFT
-    check (padded columns hold zero planes, so the padded row-sum equals
-    the logical one).
+    check against ``a_values``, the (M, K) codes at logical K (padded
+    columns hold zero planes, so the padded row-sum equals the logical
+    one).
 
     Interpret-mode verify takes the raw integer kernel plus a jnp
     epilogue instead: the interpreter charges per grid-step ref traffic,
@@ -612,29 +634,27 @@ def exact_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
     row-sum rides the accumulator tile already in VMEM."""
     from repro.kernels.pim_matmul import ops as pim_ops
     from repro.kernels.runtime import resolve_interpret
-    with jax.named_scope(SCOPE_ACT_QUANT):
-        a_q, a_planes = _quantize_activations(x2, cfg)
-        ap = _pad_act_planes(a_planes, plan)
+    _check_widths(cfg)
     with jax.named_scope(SCOPE_KERNEL):
-        _log_kernel_call(x2.shape[0], ap.shape[0], plan)
+        _log_kernel_call(ap.shape[1], ap.shape[0], plan)
         if verify and resolve_interpret(cfg.interpret):
             acc = pim_ops.pim_matmul_int(ap, plan.planes,
                                          interpret=cfg.interpret)
-            _abft_report_exact(acc.sum(axis=1, dtype=jnp.int32), a_q.values,
+            _abft_report_exact(acc.sum(axis=1, dtype=jnp.int32), a_values,
                                plan, cfg)
-            out = acc.astype(jnp.float32) * a_q.scale * plan.padded_scale
+            out = acc.astype(jnp.float32) * a_scale * plan.padded_scale
             pb = _pad_bias(bias, plan)
             if pb is not None:
                 out = out + pb
             return out[:, :plan.n]
-        res = pim_ops.pim_matmul_fused(ap, plan.planes, a_q.scale,
+        res = pim_ops.pim_matmul_fused(ap, plan.planes, a_scale,
                                        plan.padded_scale,
                                        bias=_pad_bias(bias, plan),
                                        interpret=cfg.interpret,
                                        want_rowsum=verify)
         if verify:
             out, rowsum = res
-            _abft_report_exact(rowsum, a_q.values, plan, cfg)
+            _abft_report_exact(rowsum, a_values, plan, cfg)
             return out[:, :plan.n]
         return res[:, :plan.n]
 

@@ -17,16 +17,18 @@ expects a smaller input than the current map, a max-pool bridges the gap).
 from __future__ import annotations
 
 import zlib
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import engine
-from repro.core.pim import (SCOPE_IM2COL, SCOPE_POOL, SCOPE_RESIDUAL,
-                            PimConfig)
+from repro.core.pim import (EXACT_PALLAS, SCOPE_ACT_QUANT, SCOPE_IM2COL,
+                            SCOPE_POOL, SCOPE_RESIDUAL, DensePlan, PimConfig,
+                            exact_pallas_planes_matmul2d)
 from repro.core.workloads import ConvSpec, DenseSpec, LayerSpec
-from repro.quant.quantize import fake_quantize
+from repro.quant.nibbles import to_nibbles
+from repro.quant.quantize import fake_quantize, quantize, scale_from_amax
 
 Params = Dict[str, Any]
 
@@ -48,19 +50,56 @@ def init_cnn(layers: Sequence[LayerSpec], key) -> Params:
     return params
 
 
+def _same_pad(x: jax.Array, spec: ConvSpec) -> jax.Array:
+    """SAME-pad the spatial axes of a (B, H, W, C) map for ``spec``."""
+    kh, kw = spec.kh, spec.kw
+    ph, pw = (kh - 1) // 2, (kw - 1) // 2
+    return jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw), (0, 0)))
+
+
+def _taps(xp: jax.Array, spec: ConvSpec) -> List[jax.Array]:
+    """The kh*kw strided views (B, oh, ow, C) of a SAME-padded map, in
+    the patches' (kh, kw) order."""
+    s, oh, ow = spec.stride, spec.out_h, spec.out_w
+    return [xp[:, i:i + oh * s:s, j:j + ow * s:s, :]
+            for i in range(spec.kh) for j in range(spec.kw)]
+
+
 def _im2col(x: jax.Array, spec: ConvSpec) -> jax.Array:
     """x: (B, H, W, C) -> patches (B, oh, ow, kh*kw*C), SAME padding."""
-    kh, kw, s = spec.kh, spec.kw, spec.stride
-    ph, pw = (kh - 1) // 2, (kw - 1) // 2
     with jax.named_scope(SCOPE_IM2COL):
-        x = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw),
-                        (0, 0)))
-        cols = []
-        oh, ow = spec.out_h, spec.out_w
-        for i in range(kh):
-            for j in range(kw):
-                cols.append(x[:, i:i + oh * s:s, j:j + ow * s:s, :])
-        return jnp.concatenate(cols, axis=-1)
+        return jnp.concatenate(_taps(_same_pad(x, spec), spec), axis=-1)
+
+
+def _im2col_planes(x: jax.Array, spec: ConvSpec, plan: DensePlan,
+                   bits: int) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, H, W, C) -> the int8 planes (Pa, M, Kp) and the f32 scales
+    (M, 1) that per-row quantization of ``_im2col(x, spec)`` at ``bits``
+    gives, K padded to the plan's, with no float patch matrix between.
+
+    A patch row's abs max is the max over its kh x kw window of each
+    pixel's channel abs max on the padded map: the same maximum of the
+    same values, so the scale and every code are bit-identical to
+    quantizing the patches. Each tap is quantized and split into planes
+    on its own, so the concatenate writes int8 planes only."""
+    s, oh, ow = spec.stride, spec.out_h, spec.out_w
+    with jax.named_scope(SCOPE_IM2COL):
+        xp = _same_pad(x, spec)
+    with jax.named_scope(SCOPE_ACT_QUANT):
+        amax = jax.lax.reduce_window(
+            jnp.max(jnp.abs(xp), axis=-1), -jnp.inf, jax.lax.max,
+            (1, spec.kh, spec.kw), (1, s, s), "VALID")[:, :oh, :ow, None]
+        scale = scale_from_amax(amax, bits)
+    with jax.named_scope(SCOPE_IM2COL):
+        planes = [to_nibbles(quantize(t, bits, scale=scale).values, bits)
+                  for t in _taps(xp, spec)]
+        pad_k = plan.planes.shape[1] - plan.k
+        if pad_k:
+            planes.append(jnp.zeros(planes[0].shape[:-1] + (pad_k,),
+                                    jnp.int8))
+        planes = jnp.concatenate(planes, axis=-1)
+    return (planes.reshape(planes.shape[0], -1, planes.shape[-1]),
+            scale.reshape(-1, 1))
 
 
 def _maxpool(x: jax.Array, factor: int) -> jax.Array:
@@ -118,6 +157,24 @@ class _Executor:
         y = x @ w
         return y if bias is None else y + bias
 
+    def _quantizes_patches(self) -> bool:
+        """Whether dense convs quantize as im2col expands: on the
+        exact-pallas substrate, unless an ABFT check wants the codes."""
+        return self.pim is not None and self.pim.verify == "off" and \
+            self.pim.resolved_substrate == EXACT_PALLAS
+
+    def _exact_conv(self, spec: ConvSpec, x: jax.Array, w: jax.Array,
+                    bias: jax.Array) -> jax.Array:
+        """A dense conv on exact-pallas: the int8 patch planes straight
+        from the map, then the kernel half of the route; bit-identical to
+        driving ``_im2col``'s patches through ``engine.matmul``."""
+        if self.quant_bits:
+            w = fake_quantize(w, self.quant_bits, axis=(0,))
+        plan = self._plan(spec.name, w)
+        ap, a_scale = _im2col_planes(x, spec, plan, self.pim.act_bits)
+        y = exact_pallas_planes_matmul2d(ap, a_scale, plan, self.pim, bias)
+        return y.reshape(x.shape[0], spec.out_h, spec.out_w, spec.out_c)
+
     def conv(self, spec: ConvSpec, x: jax.Array, relu: bool = True
              ) -> jax.Array:
         with jax.named_scope(spec.name):
@@ -125,9 +182,12 @@ class _Executor:
                 x = _maxpool(x, x.shape[1] // spec.in_h)
             p = self.params[spec.name]
             if spec.groups == 1:
-                cols = _im2col(x, spec)
-                y = self.matmul(cols, p["w"].reshape(-1, spec.out_c), (0,),
-                                spec.name, bias=p["b"])
+                w = p["w"].reshape(-1, spec.out_c)
+                if self._quantizes_patches():
+                    y = self._exact_conv(spec, x, w, p["b"])
+                else:
+                    y = self.matmul(_im2col(x, spec), w, (0,), spec.name,
+                                    bias=p["b"])
             else:                                      # depthwise
                 cols = _im2col(x, spec)
                 b, oh, ow, _ = cols.shape

@@ -60,6 +60,14 @@ def compute_scale(x: jax.Array, bits: int,
     """abs-max symmetric scale. ``axis=None`` -> per-tensor."""
     amax = jnp.max(jnp.abs(x)) if axis is None else jnp.max(
         jnp.abs(x), axis=tuple(axis), keepdims=True)
+    return scale_from_amax(amax, bits, eps)
+
+
+def scale_from_amax(amax: jax.Array, bits: int, eps: float = 1e-8
+                    ) -> jax.Array:
+    """The symmetric scale of values whose abs max is ``amax``, for
+    callers that reach the abs max another way than a reduction over the
+    values (a conv's patch rows, from the unexpanded map)."""
     return jnp.maximum(amax, eps) / qmax(bits)
 
 

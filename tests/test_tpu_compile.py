@@ -124,20 +124,17 @@ def test_data_only_mesh_matmul_compiles(topo):
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
 
 
-def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip):
-    """The benchmark cell's forward at its rehearsal sizes: every Mosaic
-    kernel's instruction carries its layer's ``kernel`` scope in the
-    compiled text, which is how a trace reduction finds the kernel's own
-    device events (the events carry no metadata)."""
+def _cnn_forward_text(one_chip):
+    """The benchmark cell's forward at its rehearsal sizes, compiled for
+    the described chip: (optimized HLO text, layer specs, batch)."""
     import dataclasses
     import pathlib
     import sys
     bench = pathlib.Path(__file__).resolve().parents[1] / "bench"
     if str(bench) not in sys.path:
         sys.path.insert(0, str(bench))
-    from harness import cells, runner, scopes
+    from harness import cells, runner
 
-    from repro.core import pim
     from repro.models.cnn import cnn_forward, init_cnn, plan_cnn_weights
 
     cell = cells.find("resnet18-cifar100.batch1024")
@@ -155,6 +152,19 @@ def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip):
     text = jax.jit(lambda p, pl, x: cnn_forward(
         p, layers, x, pim=pcfg, plans=pl)).lower(
             place(params), place(plans), place(x)).compile().as_text()
+    return text, layers, mix["batch"]
+
+
+def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip):
+    """The benchmark cell's forward at its rehearsal sizes: every Mosaic
+    kernel's instruction carries its layer's ``kernel`` scope in the
+    compiled text, which is how a trace reduction finds the kernel's own
+    device events (the events carry no metadata)."""
+    text, layers, _ = _cnn_forward_text(one_chip)
+    from harness import scopes
+
+    from repro.core import pim
+
     names = scopes.op_names(text)
     kernels = [scopes.instruction(line) for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
@@ -162,3 +172,36 @@ def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip):
     known = pim.SCOPES + tuple(spec.name for spec in layers)
     assert [scopes.innermost(names.get(k), known) for k in kernels] == \
         [pim.SCOPE_KERNEL] * len(layers)
+
+
+def test_cnn_forward_writes_no_float_patch_buffer(one_chip):
+    """No buffer of the compiled forward (an instruction of its entry
+    computation) is an f32 or s32 array shaped like a 3x3 conv's patch
+    matrix, (B, oh, ow, K') in any order of (B, oh, ow) with K' at least
+    its K, or (M, K): the exact route writes the patches as int8 planes
+    only."""
+    import re
+
+    from repro.core.workloads import ConvSpec
+
+    text, layers, b = _cnn_forward_text(one_chip)
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    buffers = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) [a-z][\w-]*\(", line)
+        if m:
+            buffers += [(m.group(1), tuple(int(d) for d in dims.split(",")))
+                        for dims in re.findall(r"\b(?:f32|s32)\[([\d,]+)\]",
+                                               m.group(2))]
+    assert buffers
+    for spec in layers:
+        if not isinstance(spec, ConvSpec) or spec.kh * spec.kw == 1:
+            continue
+        k = spec.kh * spec.kw * spec.in_c
+        lead = (b, spec.out_h, spec.out_w)
+        patch = [(name, shape) for name, shape in buffers
+                 if (len(shape) == 4 and sorted(shape[:3]) == sorted(lead)
+                     and shape[3] >= k)
+                 or shape == (b * spec.out_h * spec.out_w, k)]
+        assert patch == [], (spec.name, patch)
