@@ -82,7 +82,8 @@ EMULATE = "emulate"
 SCOPE_IM2COL = "im2col"          # SAME pad, strided slices, concatenate
                                  # (dense conv on exact-pallas: the round
                                  # and planes of each tap too)
-SCOPE_POOL = "pool"              # stage bridge, global average pool
+SCOPE_POOL = "pool"              # stage bridge, a dense head's max-pool
+                                 # or global average
 SCOPE_ACT_QUANT = "act_quant"    # per-row scale, round, planes, K pad
                                  # (dense conv on exact-pallas: the scale)
 SCOPE_KERNEL = "kernel"          # the pallas_call, its tile pads, slice

@@ -58,9 +58,15 @@ class ConvSpec:
 
 @dataclasses.dataclass(frozen=True)
 class DenseSpec:
+    """A dense layer. One that flattens a (B, H, W, C) map states the
+    spatial size it flattens, ``in_h`` x ``in_w`` (``in_features`` is
+    then in_h * in_w * C); with none stated (0) it takes the map's global
+    average over space, and ``in_features`` is C."""
     name: str
     in_features: int
     out_features: int
+    in_h: int = 0
+    in_w: int = 0
 
     @property
     def macs(self) -> int:
@@ -235,7 +241,7 @@ def vgg16(num_classes: int = 10, hw: int = 224, width: float = 1.0
             in_c = ch
         h = h // 2  # maxpool
     flat = in_c * h * h
-    layers.append(DenseSpec("fc1", flat, c(4096)))
+    layers.append(DenseSpec("fc1", flat, c(4096), in_h=h, in_w=h))
     layers.append(DenseSpec("fc2", c(4096), c(4096)))
     layers.append(DenseSpec("fc3", c(4096), num_classes))
     return layers

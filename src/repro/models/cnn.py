@@ -10,9 +10,12 @@ with core/workloads.py). Supports:
 The executor is structure-aware, keyed on the builders' deterministic layer
 names: ResNet basic blocks (c1/c2/ds + residual), Inception branches
 (b1 | b3r→b3 | b5r→b5a→b5b | pool→bp, concatenated), SqueezeNet fire
-modules (sq → e1‖e3 concat), MobileNet/VGG sequential. Pooling between
-stages is inferred from the specs' spatial bookkeeping (when a layer
-expects a smaller input than the current map, a max-pool bridges the gap).
+modules (sq → e1‖e3 concat), MobileNet/VGG sequential. Pooling follows
+the specs' spatial bookkeeping: when a conv expects a smaller input than
+the current map, a max-pool bridges the gap; a dense layer that flattens
+a map states the size it flattens (``DenseSpec.in_h``/``in_w``) and the
+map is max-pooled down to it, while one that states none takes the
+global average. A map that fits neither raises.
 """
 from __future__ import annotations
 
@@ -107,6 +110,27 @@ def _maxpool(x: jax.Array, factor: int) -> jax.Array:
         return jax.lax.reduce_window(
             x, -jnp.inf, jax.lax.max, (1, factor, factor, 1),
             (1, factor, factor, 1), "VALID")
+
+
+def _dense_rows(x: jax.Array, spec: DenseSpec) -> jax.Array:
+    """A (B, H, W, C) map as the (B, in_features) rows ``spec`` takes:
+    max-pooled to its stated in_h x in_w and flattened in (h, w, c)
+    order, or, where it states no size, averaged over space."""
+    b, h, w, c = x.shape
+    if spec.in_h:
+        f = h // spec.in_h
+        if (h, w) == (f * spec.in_h, f * spec.in_w) and \
+                spec.in_features == spec.in_h * spec.in_w * c:
+            if f > 1:
+                x = _maxpool(x, f)
+            return x.reshape(b, -1)
+    elif spec.in_features == c:
+        with jax.named_scope(SCOPE_POOL):
+            return jnp.mean(x, axis=(1, 2))
+    stated = f"{spec.in_h}x{spec.in_w}" if spec.in_h else "global average"
+    raise ValueError(
+        f"dense layer {spec.name!r} takes {spec.in_features} features "
+        f"({stated}); a {h}x{w}x{c} map cannot give them")
 
 
 class _Executor:
@@ -208,11 +232,7 @@ class _Executor:
     def dense(self, spec: DenseSpec, x: jax.Array, relu: bool) -> jax.Array:
         with jax.named_scope(spec.name):
             if x.ndim == 4:
-                if spec.in_features == x.shape[1] * x.shape[2] * x.shape[3]:
-                    x = x.reshape(x.shape[0], -1)
-                else:
-                    with jax.named_scope(SCOPE_POOL):
-                        x = jnp.mean(x, axis=(1, 2))
+                x = _dense_rows(x, spec)
             y = self.matmul(x, self.params[spec.name]["w"], (0,), spec.name,
                             bias=self.params[spec.name]["b"])
             return jax.nn.relu(y) if relu else y

@@ -124,9 +124,12 @@ def test_data_only_mesh_matmul_compiles(topo):
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
 
 
-def _cnn_forward_text(one_chip):
-    """The benchmark cell's forward at its rehearsal sizes, compiled for
-    the described chip: (optimized HLO text, layer specs, batch)."""
+CNN_CELLS = ["resnet18-cifar100.batch1024", "vgg16-imagenette.batch64"]
+
+
+def _cnn_forward(one_chip, cell_name, rehearse):
+    """A CNN benchmark cell's forward, at its rehearsal sizes or its own,
+    compiled for the described chip: (compiled, layer specs, batch)."""
     import dataclasses
     import pathlib
     import sys
@@ -137,8 +140,8 @@ def _cnn_forward_text(one_chip):
 
     from repro.models.cnn import cnn_forward, init_cnn, plan_cnn_weights
 
-    cell = cells.find("resnet18-cifar100.batch1024")
-    cfg, mix = runner.effective(cell, True)
+    cell = cells.find(cell_name)
+    cfg, mix = runner.effective(cell, rehearse)
     drv = cells.driver(cell)
     layers = drv.layer_specs(cfg)
     pcfg = dataclasses.replace(drv.pim_config(cfg), interpret=False)
@@ -149,18 +152,27 @@ def _cnn_forward_text(one_chip):
                            params)
     hw = cfg["image_size"]
     x = jax.ShapeDtypeStruct((mix["batch"], hw, hw, 3), jnp.float32)
-    text = jax.jit(lambda p, pl, x: cnn_forward(
+    compiled = jax.jit(lambda p, pl, x: cnn_forward(
         p, layers, x, pim=pcfg, plans=pl)).lower(
-            place(params), place(plans), place(x)).compile().as_text()
-    return text, layers, mix["batch"]
+            place(params), place(plans), place(x)).compile()
+    return compiled, layers, mix["batch"]
 
 
-def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip):
-    """The benchmark cell's forward at its rehearsal sizes: every Mosaic
+@functools.lru_cache(maxsize=None)
+def _cnn_forward_text(one_chip, cell_name):
+    """The cell's forward at its rehearsal sizes, compiled for the
+    described chip: (optimized HLO text, layer specs, batch)."""
+    compiled, layers, b = _cnn_forward(one_chip, cell_name, True)
+    return compiled.as_text(), layers, b
+
+
+@pytest.mark.parametrize("cell", CNN_CELLS)
+def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip, cell):
+    """A CNN cell's forward at its rehearsal sizes: every Mosaic
     kernel's instruction carries its layer's ``kernel`` scope in the
     compiled text, which is how a trace reduction finds the kernel's own
     device events (the events carry no metadata)."""
-    text, layers, _ = _cnn_forward_text(one_chip)
+    text, layers, _ = _cnn_forward_text(one_chip, cell)
     from harness import scopes
 
     from repro.core import pim
@@ -174,7 +186,8 @@ def test_cnn_forward_kernels_sit_under_kernel_scopes(one_chip):
         [pim.SCOPE_KERNEL] * len(layers)
 
 
-def test_cnn_forward_writes_no_float_patch_buffer(one_chip):
+@pytest.mark.parametrize("cell", CNN_CELLS)
+def test_cnn_forward_writes_no_float_patch_buffer(one_chip, cell):
     """No buffer of the compiled forward (an instruction of its entry
     computation) is an f32 or s32 array shaped like a 3x3 conv's patch
     matrix, (B, oh, ow, K') in any order of (B, oh, ow) with K' at least
@@ -184,7 +197,7 @@ def test_cnn_forward_writes_no_float_patch_buffer(one_chip):
 
     from repro.core.workloads import ConvSpec
 
-    text, layers, b = _cnn_forward_text(one_chip)
+    text, layers, b = _cnn_forward_text(one_chip, cell)
     entry = text[text.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
     buffers = []
@@ -205,3 +218,15 @@ def test_cnn_forward_writes_no_float_patch_buffer(one_chip):
                      and shape[3] >= k)
                  or shape == (b * spec.out_h * spec.out_w, k)]
         assert patch == [], (spec.name, patch)
+
+
+def test_vgg_cell_forward_compiles_at_full_size(one_chip):
+    """The VGG16 cell's forward at its own sizes (batch 64, 224 px, every
+    published width; fc1 flattens 25,088 features): Mosaic accepts every
+    kernel, and its temporaries and arguments leave the 16 GB chip room."""
+    compiled, layers, _ = _cnn_forward(one_chip, "vgg16-imagenette.batch64",
+                                       False)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == len(layers)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12e9
