@@ -323,20 +323,20 @@ PlannedDepthwiseWeights = DepthwisePlan
 def plan_from_qtensor(w_q: QTensor, cfg: PimConfig = DEFAULT_PIM
                       ) -> DensePlan:
     """Plan already-quantized (K, N) codes: decompose into nibble planes and
-    pre-pad to the kernel tile multiples."""
-    from repro.kernels.pim_matmul.pim_matmul import kernel_tiles
+    pre-pad them to the plan's layout (``plan_padding``)."""
+    from repro.kernels.pim_matmul.pim_matmul import plan_padding
     if cfg.weight_bits != w_q.bits:
         # adopted codes define the weight width; the stamped cfg must agree
         # with plan.bits or engine.matmul's consistency guard rejects it
         cfg = dataclasses.replace(cfg, weight_bits=w_q.bits)
     k, n = w_q.values.shape
     planes = to_nibbles(w_q.values, w_q.bits)              # (Pw, K, N)
-    _, bn, bk = kernel_tiles(1, k, n)
-    pad_k, pad_n = (-k) % bk, (-n) % bn
+    kp, np_ = plan_padding(k, n)
+    pad_k, pad_n = kp - k, np_ - n
     # Also land K on a WDM-chunk boundary so the analog substrates consume
     # the same pre-padded planes with no per-call re-pad (chunk boundaries
     # are absolute, so trailing zeros are exact on every route; for the
-    # default chunk=8 this is always already satisfied when k >= bk).
+    # default chunk=8 this is always already satisfied when k >= 512).
     chunk = min(cfg.wdm_chunk, k) if cfg.wdm_chunk > 0 else k
     pad_k += (-(k + pad_k)) % chunk
     if pad_k or pad_n:
@@ -547,8 +547,15 @@ def _pad_bias(bias: Optional[jax.Array], plan: DensePlan
 @dataclasses.dataclass(frozen=True)
 class KernelCall:
     """One ``pim_matmul`` call as traced: where it was, its logical
-    (M, K, N) and the tile-padded (Mp, Kp, Np) the kernel issues, and
-    its activation and weight plane counts."""
+    (M, K, N), the (Mp, Kp, Np) the kernel issues, the tiles (bm, bn, bk)
+    its grid runs on, and its activation and weight plane counts.
+
+    Two contracts meet here. The plan's padding (``plan_padding``) fixes
+    Kp and Np once, when the weights are programmed; the call's tiles
+    (``kernel_tiles``) divide them and are chosen per call from (M, Kp,
+    Np). Mp is M rounded up to whole M tiles: the last one may be
+    partial, and the planes are never padded along M. The grid runs
+    (Mp/bm) x (Np/bn) x (Kp/bk) steps."""
     scope: str                   # name stack at the call, "s0b0c1/kernel"
     m: int
     k: int
@@ -558,6 +565,9 @@ class KernelCall:
     np: int
     pa: int
     pw: int
+    bm: int
+    bn: int
+    bk: int
 
 
 _CALL_LOG: contextvars.ContextVar[Optional[List[KernelCall]]] = \
@@ -581,19 +591,19 @@ def kernel_calls(fn: Callable, *args) -> List[KernelCall]:
     return log
 
 
-def _log_kernel_call(m: int, pa: int, plan: DensePlan) -> None:
+def _log_kernel_call(m: int, pa: int, plan: DensePlan,
+                     want_rowsum: bool) -> None:
     log = _CALL_LOG.get()
     if log is None:
         return
     from jax._src import source_info_util
-    from repro.kernels.pim_matmul.pim_matmul import kernel_tiles
-    pw, kp, np_ = plan.planes.shape
-    bm, bn, bk = kernel_tiles(m, kp, np_)
-    up = lambda v, b: -(-v // b) * b
+    from repro.kernels.pim_matmul.pim_matmul import issued_tiles
+    pw, k, n = plan.planes.shape
+    bm, bn, bk, kp, np_ = issued_tiles(m, k, n, pa, pw, want_rowsum)
     log.append(KernelCall(
         scope=str(source_info_util.current_name_stack()), m=m, k=plan.k,
-        n=plan.n, mp=up(m, bm), kp=up(kp, bk), np=up(np_, bn), pa=pa,
-        pw=pw))
+        n=plan.n, mp=-(-m // bm) * bm, kp=kp, np=np_, pa=pa, pw=pw, bm=bm,
+        bn=bn, bk=bk))
 
 
 def exact_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
@@ -636,9 +646,11 @@ def exact_pallas_planes_matmul2d(ap: jax.Array, a_scale: jax.Array,
     from repro.kernels.pim_matmul import ops as pim_ops
     from repro.kernels.runtime import resolve_interpret
     _check_widths(cfg)
+    raw = verify and resolve_interpret(cfg.interpret)
     with jax.named_scope(SCOPE_KERNEL):
-        _log_kernel_call(ap.shape[1], ap.shape[0], plan)
-        if verify and resolve_interpret(cfg.interpret):
+        _log_kernel_call(ap.shape[1], ap.shape[0], plan,
+                         want_rowsum=verify and not raw)
+        if raw:
             acc = pim_ops.pim_matmul_int(ap, plan.planes,
                                          interpret=cfg.interpret)
             _abft_report_exact(acc.sum(axis=1, dtype=jnp.int32), a_values,

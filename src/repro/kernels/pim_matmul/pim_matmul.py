@@ -20,22 +20,25 @@ Two entry points:
     trailing ``mul+add`` into an FMA — one rounding instead of two, i.e.
     at least as accurate as the eager two-step reference, within 1 ulp).
 
-Tiling:
-  grid = (M/bm, N/bn, K/bk); K is the innermost (sequential) axis so each
-  (m, n) output tile accumulates across K steps in a VMEM scratch
-  accumulator, written out on the last K step. Plane pairs are unrolled
-  inside the kernel body (Pa, Pw <= 2 in practice: 4b/8b operands).
-  ``kernel_tiles`` is the deterministic tile chooser shared with the
-  engine's :class:`~repro.core.pim.PlannedWeights` pre-padding: weight
-  planes padded at programming time always land on the same tile grid the
-  kernel would pick, so the per-call padding is a no-op.
+Padding and tiling are two separate contracts:
+  * ``plan_padding`` is the layout weights are programmed into once
+    (:func:`repro.core.pim.plan_from_qtensor`): K to a multiple of
+    ``PLAN_BK``, N to a multiple of ``PLAN_BN``. The analog substrates,
+    fault injection and im2col's zero tail all read that layout.
+  * ``kernel_tiles`` picks, per call, the (bm, bn, bk) the grid runs on
+    from the issued (M, Kp, Np): tiles that divide Kp and Np, the whole
+    K in one step where it fits, under ``TILE_BUDGET`` of VMEM. A grid
+    step has a fixed cost (~0.2 us on a v5e, fitted to the kernel's
+    measured times), so few large steps beat many small ones on the
+    CNN's million-row im2col GEMMs.
 
-VMEM budget per step (bm=bn=128, bk=512, Pa=Pw=2):
-  a tile 2*128*512 B + w tile 2*512*128 B + acc 128*128*4 B ~= 0.33 MiB,
-  comfortably inside the ~16 MiB VMEM of a TPU v5e core, leaving room for
-  double-buffered prefetch of the next K tiles.
-
-dot dims are (128, 512) x (512, 128) — MXU-aligned (multiples of 128).
+  grid = (cdiv(M, bm), Np/bn, Kp/bk); K is the innermost (sequential)
+  axis so each (m, n) output tile accumulates across K steps in a VMEM
+  scratch accumulator, written out on the last K step. Activation
+  planes are never padded along M: the last M block may be partial, and
+  its rows beyond M only reach output rows that Pallas does not write.
+  Plane pairs are unrolled inside the kernel body (Pa, Pw <= 2: 4b/8b
+  operands).
 """
 from __future__ import annotations
 
@@ -47,9 +50,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BM = 128
-DEFAULT_BN = 128
-DEFAULT_BK = 512
+# The plan's padding: K to whole 512-deep blocks, N to whole 128 lanes.
+PLAN_BK = 512
+PLAN_BN = 128
 
 # Mosaic register-tile geometry for float32 operands: the fused epilogue's
 # scale vectors are padded to full (sublane, lane) tiles so compiled
@@ -57,19 +60,107 @@ DEFAULT_BK = 512
 # real-TPU Mosaic wants lane-aligned operands).
 LANE = 128
 SUBLANE = 8
+# int8 packs four rows per sublane: an int8 block's row count is a
+# multiple of 32 unless it spans the whole dim
+SUBLANE_I8 = 32
+
+# Call tiles: rows per M tile and columns per N tile at most. Past ~2k
+# rows a step's DMA (~2.6 us for one 2048 x 1024 int8 plane block at
+# 819 GB/s) already dwarfs its fixed cost.
+MAX_BM = 2048
+MAX_BN = 512
+# Scoped VMEM the kernel asks of Mosaic (v5e: 128 MiB per core, 16 MiB
+# scoped by default), and the share of it the chooser lets the blocks
+# take; the rest is Mosaic's own (dot results, internal scratch).
+VMEM_LIMIT = 48 * 2 ** 20
+TILE_BUDGET = 24 * 2 ** 20
 
 
-def kernel_tiles(m: int, k: int, n: int, bm: int = DEFAULT_BM,
-                 bn: int = DEFAULT_BN, bk: int = DEFAULT_BK
-                 ) -> Tuple[int, int, int]:
-    """Deterministic (bm, bn, bk) tile selection for problem (M, K, N).
+def _up(v: int, b: int) -> int:
+    return -(-v // b) * b
 
-    Shared between the kernel wrappers and ``prepare_weights`` so that
-    planes padded once at weight-programming time stay valid for every
-    subsequent call: for any K' that is a multiple of ``ceil(k/bk)*bk``
-    the recomputed tile divides it exactly.
-    """
-    return min(bm, m), min(bn, n), min(bk, k)
+
+def plan_padding(k: int, n: int) -> Tuple[int, int]:
+    """(Kp, Np) the plan pads a (K, N) weight to: K to a multiple of
+    ``PLAN_BK`` (or K itself below that), N to a multiple of ``PLAN_BN``
+    (or N itself below that). Idempotent on its own output."""
+    return _up(k, min(PLAN_BK, k)), _up(n, min(PLAN_BN, n))
+
+
+def tile_vmem_bytes(bm: int, bn: int, bk: int, pa: int, pw: int,
+                    want_rowsum: bool = False) -> int:
+    """VMEM the fused kernel's blocks take at tiles (bm, bn, bk): the
+    plane, scale, bias and output blocks double-buffered, the int32
+    accumulator once, each padded to whole (sublane, lane) tiles."""
+    rows, lanes = _up(bm, SUBLANE), _up(bn, LANE)
+    planes = (pa * _up(bm, SUBLANE_I8) * _up(bk, LANE)
+              + pw * _up(bk, SUBLANE_I8) * lanes)
+    scales = rows * LANE * 4 + 2 * SUBLANE * lanes * 4
+    out = rows * lanes * 4 + (rows * LANE * 4 if want_rowsum else 0)
+    return 2 * (planes + scales + out) + rows * lanes * 4
+
+
+def _largest_divisor(v: int, step: int, cap: int) -> Optional[int]:
+    """Largest multiple of ``step`` that divides ``v`` and is at most
+    ``cap``; None where there is none."""
+    return next((d for d in range(cap - cap % step, 0, -step)
+                 if v % d == 0), None)
+
+
+def _row_tile(m: int, cap: int) -> int:
+    """M tile of at most ``cap`` rows: M itself when it fits, else the
+    largest divisor of M that is a multiple of 32 and at least half the
+    cap, else equal tiles rounded up to 32 rows (the last one partial)."""
+    if m <= cap:
+        return m
+    d = _largest_divisor(m, SUBLANE_I8, cap)
+    if d and 2 * d >= cap:
+        return d
+    return _up(-(-m // -(-m // cap)), SUBLANE_I8)
+
+
+def kernel_tiles(m: int, kp: int, np_: int, pa: int = 1, pw: int = 1,
+                 want_rowsum: bool = False) -> Tuple[int, int, int]:
+    """(bm, bn, bk) for one call on issued (M, Kp, Np), Kp and Np as
+    ``plan_padding`` leaves them.
+
+    * bn: the whole Np up to ``MAX_BN``, else its largest divisor that is
+      a multiple of 128 and at most ``MAX_BN``.
+    * bk: the whole Kp where a 512-row tile (or all of M) fits the
+      budget, else the largest multiple of 512 dividing Kp that fits.
+    * bm: ``_row_tile`` of M under the largest cap (2048 halved) that
+      fits the budget at that bk.
+
+    ``plan_padding`` leaves Np above 512 a multiple of 128 and Kp above
+    512 a multiple of 512, so both divisors exist. Every block's last two
+    dims are then multiples of (32, 128) for int8 and (8, 128) for f32,
+    or the whole dim, as Mosaic requires."""
+    bn = np_ if np_ <= MAX_BN else _largest_divisor(np_, LANE, MAX_BN)
+
+    def fits(rows: int, bk: int) -> bool:
+        return tile_vmem_bytes(min(m, rows), bn, bk, pa, pw,
+                               want_rowsum) <= TILE_BUDGET
+
+    ks = [kp] + [d for d in range(kp - PLAN_BK, 0, -PLAN_BK)
+                 if kp % d == 0]
+    bk = next((d for d in ks if fits(MAX_BM // 4, d)), min(kp, PLAN_BK))
+    cap = MAX_BM
+    while cap > SUBLANE_I8 and not fits(cap, bk):
+        cap //= 2
+    return _row_tile(m, cap), bn, bk
+
+
+def issued_tiles(m: int, k: int, n: int, pa: int, pw: int,
+                 want_rowsum: bool = False, bm: Optional[int] = None,
+                 bn: Optional[int] = None, bk: Optional[int] = None
+                 ) -> Tuple[int, int, int, int, int]:
+    """What a kernel wrapper issues for planes (Pa, M, K) x (Pw, K, N):
+    (bm, bn, bk, Kp, Np), tiles from ``kernel_tiles`` unless given, and K
+    and N padded to the plan's layout and then to whole tiles."""
+    kp, np_ = plan_padding(k, n)
+    tm, tn, tk = kernel_tiles(m, kp, np_, pa, pw, want_rowsum)
+    bm, bn, bk = min(bm or tm, m), min(bn or tn, np_), min(bk or tk, kp)
+    return bm, bn, bk, _up(kp, bk), _up(np_, bn)
 
 
 def _plane_pairs_acc(a_ref, w_ref, acc, pa: int, pw: int):
@@ -84,6 +175,18 @@ def _plane_pairs_acc(a_ref, w_ref, acc, pa: int, pw: int):
                 preferred_element_type=jnp.int32)
             acc = acc + partial * (16 ** (d + e))
     return acc
+
+
+def _pad_planes(a_planes, w_planes, kp: int, np_: int):
+    """Zero-pad the planes along K and N to the issued Kp, Np (exact for
+    an integer matmul); never along M. Planes from a plan arrive padded
+    already, so this copies only for direct callers."""
+    k, n = w_planes.shape[1:]
+    if kp > k:
+        a_planes = jnp.pad(a_planes, ((0, 0), (0, 0), (0, kp - k)))
+    if kp > k or np_ > n:
+        w_planes = jnp.pad(w_planes, ((0, 0), (0, kp - k), (0, np_ - n)))
+    return a_planes, w_planes
 
 
 def _pim_matmul_kernel(a_ref, w_ref, o_ref, acc_ref, *, n_k: int,
@@ -110,14 +213,15 @@ def _pim_matmul_kernel(a_ref, w_ref, o_ref, acc_ref, *, n_k: int,
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def pim_matmul_pallas(a_planes: jax.Array, w_planes: jax.Array,
-                      bm: int = 128, bn: int = 128, bk: int = 512,
+                      bm: Optional[int] = None, bn: Optional[int] = None,
+                      bk: Optional[int] = None,
                       interpret: bool = False) -> jax.Array:
     """Bit-sliced integer matmul via pallas_call.
 
     Args:
       a_planes: (Pa, M, K) int8 nibble planes of the activations.
       w_planes: (Pw, K, N) int8 nibble planes of the weights.
-      bm/bn/bk: VMEM tile sizes (MXU-aligned).
+      bm/bn/bk: VMEM tile sizes; ``kernel_tiles`` picks those not given.
       interpret: run in interpreter mode (CPU validation).
 
     Returns:
@@ -127,31 +231,27 @@ def pim_matmul_pallas(a_planes: jax.Array, w_planes: jax.Array,
     pw, k2, n = w_planes.shape
     assert k == k2, f"contraction mismatch {k} vs {k2}"
 
-    bm, bn, bk = kernel_tiles(m, k, n, bm, bn, bk)
-    # pad to tile multiples (zero padding is exact for integer matmul)
-    pad_m, pad_n, pad_k = (-m) % bm, (-n) % bn, (-k) % bk
-    if pad_m or pad_k:
-        a_planes = jnp.pad(a_planes, ((0, 0), (0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        w_planes = jnp.pad(w_planes, ((0, 0), (0, pad_k), (0, pad_n)))
-    mp, kp, np_ = m + pad_m, k + pad_k, n + pad_n
+    bm, bn, bk, kp, np_ = issued_tiles(m, k, n, pa, pw, bm=bm, bn=bn,
+                                       bk=bk)
+    a_planes, w_planes = _pad_planes(a_planes, w_planes, kp, np_)
     n_k = kp // bk
 
     out = pl.pallas_call(
         functools.partial(_pim_matmul_kernel, n_k=n_k, pa=pa, pw=pw),
-        grid=(mp // bm, np_ // bn, n_k),
+        grid=(pl.cdiv(m, bm), np_ // bn, n_k),
         in_specs=[
             pl.BlockSpec((pa, bm, bk), lambda i, j, s: (0, i, s)),
             pl.BlockSpec((pw, bk, bn), lambda i, j, s: (0, s, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((m, np_), jnp.int32),
         # int32 accumulator tile, persistent across the sequential K axis
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="pim_matmul_int",
     )(a_planes, w_planes)
-    return out[:m, :n]
+    return out[:, :n]
 
 
 def _pim_matmul_fused_kernel(a_ref, w_ref, as_ref, ws_ref, *rest, n_k: int,
@@ -223,8 +323,9 @@ def _pim_matmul_fused_kernel(a_ref, w_ref, as_ref, ws_ref, *rest, n_k: int,
 def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
                             a_scale: jax.Array, w_scale: jax.Array,
                             bias: Optional[jax.Array] = None,
-                            bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                            bk: int = DEFAULT_BK,
+                            bm: Optional[int] = None,
+                            bn: Optional[int] = None,
+                            bk: Optional[int] = None,
                             interpret: bool = False,
                             lane_pad: bool = True,
                             want_rowsum: bool = False):
@@ -236,7 +337,7 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
       a_scale: (M, 1) f32 per-row dynamic activation scales.
       w_scale: (1, N) f32 per-column weight scales.
       bias: optional (1, N) f32, added after dequantization.
-      bm/bn/bk: VMEM tile sizes (MXU-aligned).
+      bm/bn/bk: VMEM tile sizes; ``kernel_tiles`` picks those not given.
       interpret: run in interpreter mode (CPU validation).
       lane_pad: pad the width-1 scale vectors to full (SUBLANE, LANE)
         register tiles so compiled Mosaic lowering is clean (default).
@@ -257,19 +358,13 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
     assert a_scale.shape == (m, 1), f"a_scale shape {a_scale.shape}"
     assert w_scale.shape == (1, n), f"w_scale shape {w_scale.shape}"
 
-    bm, bn, bk = kernel_tiles(m, k, n, bm, bn, bk)
-    pad_m, pad_n, pad_k = (-m) % bm, (-n) % bn, (-k) % bk
-    if pad_m or pad_k:
-        a_planes = jnp.pad(a_planes, ((0, 0), (0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        w_planes = jnp.pad(w_planes, ((0, 0), (0, pad_k), (0, pad_n)))
-    if pad_m:
-        a_scale = jnp.pad(a_scale, ((0, pad_m), (0, 0)))
-    if pad_n:
-        w_scale = jnp.pad(w_scale, ((0, 0), (0, pad_n)))
+    bm, bn, bk, kp, np_ = issued_tiles(m, k, n, pa, pw, want_rowsum,
+                                       bm=bm, bn=bn, bk=bk)
+    a_planes, w_planes = _pad_planes(a_planes, w_planes, kp, np_)
+    if np_ > n:
+        w_scale = jnp.pad(w_scale, ((0, 0), (0, np_ - n)))
         if bias is not None:
-            bias = jnp.pad(bias, ((0, 0), (0, pad_n)))
-    mp, kp, np_ = m + pad_m, k + pad_k, n + pad_n
+            bias = jnp.pad(bias, ((0, 0), (0, np_ - n)))
     n_k = kp // bk
     has_bias = bias is not None
 
@@ -301,25 +396,26 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
         inputs.append(bias)
 
     out_specs = pl.BlockSpec((bm, bn), lambda i, j, s: (i, j))
-    out_shape = jax.ShapeDtypeStruct((mp, np_), jnp.float32)
+    out_shape = jax.ShapeDtypeStruct((m, np_), jnp.float32)
     if want_rowsum:
         # one private (bm, LANE) partial per (i, j) tile; the j-block
         # fold happens below in plain jnp (a handful of int32 columns)
         out_specs = (out_specs,
                      pl.BlockSpec((bm, LANE), lambda i, j, s: (i, j)))
         out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((mp, (np_ // bn) * LANE),
+                     jax.ShapeDtypeStruct((m, (np_ // bn) * LANE),
                                           jnp.int32))
 
     out = pl.pallas_call(
         functools.partial(_pim_matmul_fused_kernel, n_k=n_k, pa=pa, pw=pw,
                           has_bias=has_bias, lane_pad=lane_pad,
                           want_rowsum=want_rowsum),
-        grid=(mp // bm, np_ // bn, n_k),
+        grid=(pl.cdiv(m, bm), np_ // bn, n_k),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="pim_matmul_fused",
     )(*inputs)
@@ -328,7 +424,7 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
         # lane 0 of each j block carries that tile's partial; int32
         # wraparound addition is associative, so this fold is bit-equal
         # to the in-kernel accumulation order
-        rowsum = partials.reshape(mp, np_ // bn, LANE)[:m, :, 0].sum(
+        rowsum = partials.reshape(m, np_ // bn, LANE)[:, :, 0].sum(
             axis=1, dtype=jnp.int32)
-        return out[:m, :n], rowsum
-    return out[:m, :n]
+        return out[:, :n], rowsum
+    return out[:, :n]

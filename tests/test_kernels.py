@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps vs pure-jnp oracles (interpret mode)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -69,6 +71,126 @@ def test_fused_epilogue_lane_padding_parity(m, k, n, with_bias):
         # epilogue is bit-exact against the whole-array reference
         assert jnp.array_equal(padded,
                                pim_matmul_fused_ref(a, w, a_s, w_s))
+
+
+def _check_tiles(m, kp, np_, pa, pw, want_rowsum=False):
+    """The chooser's invariants at one issued (M, Kp, Np): the tiles
+    divide Kp and Np and only the last M tile may be partial; every
+    block's last two dims are multiples of (32, 128) for int8 and (8,
+    128) for f32, or the whole dim; the blocks fit the VMEM budget."""
+    from repro.kernels.pim_matmul import pim_matmul as pk
+    bm, bn, bk = pk.kernel_tiles(m, kp, np_, pa, pw, want_rowsum)
+    assert kp % bk == 0 and np_ % bn == 0, (bm, bn, bk)
+    assert 0 < bm <= m and -(-m // bm) * bm - m < bm
+    assert bm <= pk.MAX_BM and bn <= pk.MAX_BN
+    aligned = lambda v, whole, step: v == whole or v % step == 0
+    assert aligned(bm, m, 32) and aligned(bk, kp, 128)     # int8 planes
+    assert aligned(bk, kp, 32) and aligned(bn, np_, 128)   # int8 weights
+    assert aligned(bm, m, 8)                               # f32 out, scales
+    assert pk.tile_vmem_bytes(bm, bn, bk, pa, pw, want_rowsum) <= \
+        pk.TILE_BUDGET < pk.VMEM_LIMIT
+    return bm, bn, bk
+
+
+def _cell_calls(cell_name):
+    """The kernel calls a CNN benchmark cell's forward logs, traced for
+    shapes only at the cell's own batch."""
+    import pathlib
+    import sys
+    bench = pathlib.Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from harness import cells, runner
+
+    from repro.core.pim import kernel_calls
+    from repro.models.cnn import cnn_forward, init_cnn, plan_cnn_weights
+    cell = cells.find(cell_name)
+    cfg, mix = runner.effective(cell, False)
+    drv = cells.driver(cell)
+    layers, pim = drv.layer_specs(cfg), drv.pim_config(cfg)
+    params = jax.eval_shape(lambda: init_cnn(layers, jax.random.PRNGKey(0)))
+    plans = jax.eval_shape(lambda p: plan_cnn_weights(p, layers, pim),
+                           params)
+    hw = cfg["image_size"]
+    x = jax.ShapeDtypeStruct((mix["batch"], hw, hw, 3), jnp.float32)
+    return kernel_calls(
+        lambda p, pl, x: cnn_forward(p, layers, x, pim=pim, plans=pl),
+        params, plans, x)
+
+
+@pytest.mark.parametrize("cell", ["resnet18-cifar100.batch1024",
+                                  "vgg16-imagenette.batch64"])
+def test_kernel_tiles_at_every_cnn_cell_call(cell):
+    """Every call of the cell gets tiles that divide M too (no partial
+    tile, so the issued work, and the pad share, is the plan's), and the
+    whole forward runs on a few thousand grid steps."""
+    calls = _cell_calls(cell)
+    steps = 0
+    for c in calls:
+        bm, bn, bk = _check_tiles(c.m, c.kp, c.np, c.pa, c.pw)
+        assert (c.bm, c.bn, c.bk) == (bm, bn, bk)
+        assert c.mp == c.m and c.m % bm == 0
+        steps += (c.m // bm) * (c.np // bn) * (c.kp // bk)
+    assert 0 < steps < 10_000
+
+
+@pytest.mark.parametrize("m", [8, 256], ids=["decode", "prefill"])
+@pytest.mark.parametrize("bits", [4, 8], ids=["w4a4", "w8a8"])
+@pytest.mark.parametrize("want_rowsum", [False, True],
+                         ids=["plain", "rowsum"])
+def test_kernel_tiles_at_lm_shapes(m, bits, want_rowsum):
+    """qwen2.5-3b's MLP up/gate projection (K 2048, N 11008): a small M
+    keeps its whole M and K in one tile, and N splits into 256-column
+    tiles (11008 is 86 lanes of 128)."""
+    from repro.kernels.pim_matmul.pim_matmul import plan_padding
+    kp, np_ = plan_padding(2048, 11008)
+    planes = bits // 4
+    assert _check_tiles(m, kp, np_, planes, planes, want_rowsum) == \
+        (m, 256, 2048)
+
+
+@pytest.mark.parametrize("m,with_bias,want_rowsum", [
+    (4096, False, False),
+    (4096, True, False),
+    (4096, False, True),
+    (4100, True, True),        # the last M tile partial
+])
+def test_fused_kernel_bit_identity_at_chosen_tiles(m, with_bias,
+                                                   want_rowsum):
+    """At K 1152 (Kp 1536) and N 64 the chooser takes several M tiles and
+    one whole-K step. The result is bit-identical to the same kernel on
+    128 x 64 x 512 tiles (three K steps), and to the whole-array
+    reference but for the fused bias add: an FMA there, one rounding of
+    the product fewer."""
+    from repro.kernels.pim_matmul.pim_matmul import (kernel_tiles,
+                                                     pim_matmul_fused_pallas,
+                                                     plan_padding)
+    from repro.kernels.pim_matmul.ref import pim_matmul_fused_ref
+    k, n = 1152, 64
+    bm, _, bk = kernel_tiles(m, *plan_padding(k, n))
+    assert m > bm and bk == plan_padding(k, n)[0]
+    key = jax.random.PRNGKey(m)
+    a = jax.random.randint(key, (1, m, k), -8, 8, dtype=jnp.int8)
+    w = jax.random.randint(jax.random.fold_in(key, 1), (1, k, n), -8, 8,
+                           dtype=jnp.int8)
+    a_s = jax.random.uniform(jax.random.fold_in(key, 2), (m, 1),
+                             minval=0.01, maxval=1.0)
+    w_s = jax.random.uniform(jax.random.fold_in(key, 3), (1, n),
+                             minval=0.01, maxval=1.0)
+    bias = jax.random.normal(jax.random.fold_in(key, 4), (1, n)) \
+        if with_bias else None
+    run = functools.partial(pim_matmul_fused_pallas, a, w, a_s, w_s, bias,
+                            interpret=True, want_rowsum=want_rowsum)
+    got, steps = run(), run(bm=128, bk=512)
+    ref = pim_matmul_fused_ref(a, w, a_s, w_s, bias, want_rowsum=want_rowsum)
+    for x, y, z in zip(*(jax.tree.leaves(t) for t in (got, steps, ref))):
+        assert jnp.array_equal(x, y)
+        if with_bias and x.dtype == jnp.float32:
+            prod = np.abs(np.asarray(pim_matmul_fused_ref(a, w, a_s, w_s)))
+            tol = np.spacing(prod) + np.spacing(np.abs(np.asarray(z)))
+            assert np.all(np.abs(np.asarray(x) - np.asarray(z)) <= tol)
+        else:
+            assert jnp.array_equal(x, z)
 
 
 @pytest.mark.parametrize("bh,l,p,n,q", [

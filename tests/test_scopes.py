@@ -18,7 +18,8 @@ from harness import cells, counts, runner, scopes  # noqa: E402
 from harness import trace as trace_mod  # noqa: E402
 
 from repro.core import pim  # noqa: E402
-from repro.kernels.pim_matmul.pim_matmul import kernel_tiles  # noqa: E402
+from repro.kernels.pim_matmul.pim_matmul import (  # noqa: E402
+    kernel_tiles, plan_padding)
 
 CELL = "resnet18-cifar100.batch1024"
 TRACE = ROOT / "bench" / "tests" / "data" / "small.xplane.pb"
@@ -33,15 +34,19 @@ def rehearsal():
 
 
 def _issued(m, k, n, cfg):
-    """(Mp, Kp, Np) the kernel issues for a logical (M, K, N): the plan
-    pads K to the K tile and then to a WDM chunk, N to the N tile; the
-    wrapper pads M to the M tile."""
+    """(Mp, Kp, Np, bm, bn, bk) the kernel issues for a logical (M, K,
+    N), from two contracts. The plan's padding, fixed once when the
+    weights are programmed: K to whole 512-deep blocks and then to a WDM
+    chunk, N to whole 128 lanes (``plan_padding``). The call's tiles,
+    chosen per call from (M, Kp, Np) (``kernel_tiles``): they divide Kp
+    and Np, and M rounds up to whole M tiles, the last possibly
+    partial."""
     up = lambda v, b: -(-v // b) * b
-    _, bn, bk = kernel_tiles(1, k, n)
-    kp = up(up(k, bk), min(cfg.wdm_chunk, k))
-    np_ = up(n, bn)
-    bm, bn, bk = kernel_tiles(m, kp, np_)
-    return up(m, bm), up(kp, bk), up(np_, bn)
+    kp, np_ = plan_padding(k, n)
+    kp = up(kp, min(cfg.wdm_chunk, k))
+    bm, bn, bk = kernel_tiles(m, kp, np_, cfg.act_planes, cfg.weight_planes)
+    assert (kp % bk, np_ % bn) == (0, 0)
+    return up(m, bm), kp, np_, bm, bn, bk
 
 
 def test_compiled_forward_puts_each_conv_under_its_scopes(rehearsal):
@@ -72,7 +77,8 @@ def test_kernel_call_log_matches_logical_and_issued_shapes(rehearsal):
                                               for m, k, n, _ in want]
     pcfg = drv.pim_config(cfg)
     for c in log:
-        assert (c.mp, c.kp, c.np) == _issued(c.m, c.k, c.n, pcfg)
+        assert (c.mp, c.kp, c.np, c.bm, c.bn, c.bk) == \
+            _issued(c.m, c.k, c.n, pcfg)
         assert (c.pa, c.pw) == (pcfg.act_planes, pcfg.weight_planes)
     assert [c.scope for c in log] == [
         f"{spec.name}/{pim.SCOPE_KERNEL}" for spec in drv.layer_specs(cfg)]
@@ -89,7 +95,7 @@ def test_pad_share_reader_at_the_cells_shapes():
     pcfg = cells.driver(cell).pim_config(cfg)
     logical = issued = 0
     for m, k, n, _ in counts.cnn_calls(cfg, mix["batch"], 1):
-        mp, kp, np_ = _issued(m, k, n, pcfg)
+        mp, kp, np_ = _issued(m, k, n, pcfg)[:3]
         logical, issued = logical + m * k * n, issued + mp * kp * np_
     assert abs(got - 100.0 * (1 - logical / issued)) < 1e-9
     assert abs(got - 25.6) < 0.1

@@ -88,6 +88,22 @@ def test_raw_pim_matmul_compiles(one_chip):
              one_chip, *_plane_shapes(DECODE_M, 1))
 
 
+# The CNN cells' extreme calls as (M, Kp, Np), w4a4 with a bias: VGG's
+# s0c1 (the most rows, 2048-row tiles), ResNet's stage-3 convs (the
+# deepest whole-K step at the widest N tile), VGG's fc1 (the deepest K,
+# split into K steps). Each takes the tiles ``kernel_tiles`` picks.
+CNN_EXTREMES = [(3211264, 1024, 64), (16384, 4608, 512), (64, 25088, 4096)]
+
+
+@pytest.mark.parametrize("m,k,n", CNN_EXTREMES,
+                         ids=["vgg-s0c1", "resnet-s3", "vgg-fc1"])
+def test_fused_pim_matmul_compiles_at_cnn_extremes(one_chip, m, k, n):
+    _compile(functools.partial(pim_matmul_fused_pallas, interpret=False),
+             one_chip, ((1, m, k), jnp.int8), ((1, k, n), jnp.int8),
+             ((m, 1), jnp.float32), ((1, n), jnp.float32),
+             ((1, n), jnp.float32))
+
+
 def test_analog_fullscale_compiles(one_chip):
     _compile(functools.partial(analog_fullscale_pallas, chunk=8,
                                interpret=False),
