@@ -4,7 +4,7 @@ invariants.
 Everything the repo's headline numbers rest on — no implicit
 device->host syncs in the decode loop, step functions compiling exactly
 once, plan pytrees registered, Pallas BlockSpecs on (8, 128) register
-tiles, deprecated config aliases staying dead — is enforced mechanically
+tiles — is enforced mechanically
 here instead of by scattered one-off test assertions:
 
   lint.py       AST lint framework: ``Finding``, the string-keyed
@@ -15,7 +15,7 @@ here instead of by scattered one-off test assertions:
                 ``ContinuousScheduler.run``, ``engine.matmul``).
   checkers.py   The repo-specific checkers (RPR1xx host-sync, RPR2xx
                 recompile hazards, RPR301 pytree registration, RPR4xx
-                Pallas tiles, RPR501 deprecated aliases).
+                Pallas tiles).
   sanitize.py   Runtime layer: ``Sanitizer`` (``transfer_guard`` around
                 the scheduler's steady-state decode window, optional NaN
                 debugging) and ``CompileCounter`` (a compile-count

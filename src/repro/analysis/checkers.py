@@ -526,8 +526,9 @@ class PallasTileChecker(Checker):
                     "RPR401", module.path, node.lineno, node.col_offset,
                     f"BlockSpec minor dim {minor} is not a multiple of "
                     f"the {LANE}-lane register tile; compiled Mosaic "
-                    "needs lane-aligned operands (pad like the "
-                    "lane_pad scale specs)")
+                    "needs lane-aligned operands (pad a width-1 vector "
+                    "to a full tile, as pim_matmul_fused_pallas does "
+                    "with its scales)")
 
     @staticmethod
     def _resolve_int(node: ast.AST, module: ModuleInfo) -> Optional[int]:
@@ -574,45 +575,7 @@ class PallasTileChecker(Checker):
                             "(kernels.runtime.resolve_interpret)")
 
 
-# ---------------------------------------------------------------------------
-class DeprecatedApiChecker(Checker):
-    """RPR501: the pre-registry route-selection aliases stay dead
-    everywhere except their definition/resolution site."""
-
-    name = "deprecated"
-    rules = ("RPR501",)
-
-    ALLOWED_MODULES = ("repro.core.pim",)
-
-    def check(self, project: Project, module: ModuleInfo
-              ) -> Iterable[Finding]:
-        if module.name in self.ALLOWED_MODULES:
-            return
-        for node in ast.walk(module.tree):
-            if isinstance(node, ast.Attribute) and \
-                    node.attr in ("use_pallas", "analog"):
-                yield Finding(
-                    "RPR501", module.path, node.lineno, node.col_offset,
-                    f"`.{node.attr}` is a deprecated PimConfig alias; "
-                    "route selection is by substrate registry key "
-                    "(cfg.resolved_substrate / substrate=...)")
-            elif isinstance(node, ast.Call):
-                fchain = attr_chain(node.func)
-                is_pim_cfg = bool(fchain) and fchain[-1] in (
-                    "PimConfig", "replace")
-                for kw in node.keywords:
-                    if kw.arg == "use_pallas" or (
-                            kw.arg == "analog" and is_pim_cfg):
-                        yield Finding(
-                            "RPR501", module.path, node.lineno,
-                            node.col_offset,
-                            f"`{kw.arg}=` is a deprecated PimConfig "
-                            "alias; pass substrate=<registry key> "
-                            "instead")
-
-
 register_checker(HostSyncChecker())
 register_checker(RecompileChecker())
 register_checker(PytreeChecker())
 register_checker(PallasTileChecker())
-register_checker(DeprecatedApiChecker())

@@ -48,8 +48,6 @@ RULES: Dict[str, str] = {
               "would silently run the Pallas interpreter)",
     "RPR403": "pallas_call without name= (a trace reduction finds a "
               "kernel by its name)",
-    "RPR501": "deprecated PimConfig alias (use_pallas / analog); use "
-              "substrate= registry keys",
 }
 
 _SUPPRESS_RE = re.compile(r"#\s*repro-lint:\s*disable=([A-Za-z0-9,\s]+)")

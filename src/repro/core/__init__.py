@@ -8,9 +8,7 @@
 """
 from repro.core.arch import DEFAULT_ARCH, OpimaArch
 from repro.core.cell import CellDesign, DEFAULT_CELL, best_design, design_space
-from repro.core.pim import (DEFAULT_PIM, PimConfig, PlannedDepthwiseWeights,
-                            PlannedWeights, pim_depthwise_matmul, pim_linear,
-                            pim_matmul, plan_from_qtensor,
+from repro.core.pim import (DEFAULT_PIM, PimConfig, plan_from_qtensor,
                             prepare_depthwise_weights, prepare_weights,
                             reference_quantized_matmul)
 from repro.core.perfmodel import (NetworkPerf, best_grouping, grouping_sweep,

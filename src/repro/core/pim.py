@@ -39,7 +39,7 @@ substrate-stamped :class:`PimConfig`):
   DepthwisePlan      (K, C) per-channel filters for grouped convolutions
   ExpertStackedPlan  (E, K, N) vmapped plans over an expert axis (MoE)
 
-Programming API (the single place weight decomposition happens):
+Programming (the single place weight decomposition happens):
 
   prepare_weights(w, cfg)            -> DensePlan
   plan_from_qtensor(w_q, cfg)        -> DensePlan (adopt existing codes)
@@ -48,23 +48,28 @@ Programming API (the single place weight decomposition happens):
   reference_quantized_matmul(x, w_q) -> oracle the exact substrates must
     match bit-for-bit.
 
-Legacy entry points :func:`pim_matmul` / :func:`pim_depthwise_matmul` /
-:func:`pim_linear` are kept for compatibility; they dispatch through
-:func:`repro.engine.matmul`. New code should use :mod:`repro.engine`
-directly: ``engine.program(w, cfg)`` once, ``engine.matmul(x, plan)`` many.
+Driving a dense plan is two steps. :func:`quantize_activations` is the
+one place a dense matmul's activations are quantized, split into planes
+and padded to the plan's K; it returns the (Pa, M, Kp) int8 planes and
+(M, 1) f32 row scales. Each integer substrate's drive
+(``*_planes_matmul2d``) takes that pair. A producer that builds the pair
+itself (a conv's im2col) drives a plan through
+``engine.matmul_planes``. Callers go through :mod:`repro.engine`:
+``engine.program(w, cfg)`` once, ``engine.matmul(x, plan)`` many.
 """
 from __future__ import annotations
 
 import contextvars
 import dataclasses
 import warnings
-from typing import Callable, List, Optional, Union
+from typing import Callable, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.cell import DEFAULT_CELL
-from repro.quant.nibbles import NIBBLE_BASE, num_nibbles, to_nibbles
+from repro.quant.nibbles import (NIBBLE_BASE, from_nibbles, num_nibbles,
+                                 to_nibbles)
 from repro.quant.quantize import QTensor, quantize
 
 # Canonical substrate names (registry keys — see repro/engine/substrates.py).
@@ -74,18 +79,19 @@ ANALOG = "analog"
 ANALOG_PALLAS = "analog-pallas"
 EMULATE = "emulate"
 
-# ``jax.named_scope`` names the CNN executor and the exact-pallas route
+# ``jax.named_scope`` names the CNN executor and the engine's drives
 # put on their ops. A profiler's device events carry no metadata, so a
 # trace reduction attributes an op to these through the compiled
 # program's ``op_name`` of the instruction (``compiled.as_text()``). None
 # contains "pim_matmul" or "forward", which trace readers match by name.
 SCOPE_IM2COL = "im2col"          # SAME pad, strided slices, concatenate
-                                 # (dense conv on exact-pallas: the round
-                                 # and planes of each tap too)
+                                 # (dense conv on an integer substrate:
+                                 # the round and planes of each tap too)
 SCOPE_POOL = "pool"              # stage bridge, a dense head's max-pool
                                  # or global average
 SCOPE_ACT_QUANT = "act_quant"    # per-row scale, round, planes, K pad
-                                 # (dense conv on exact-pallas: the scale)
+                                 # (dense conv on an integer substrate:
+                                 # the scale)
 SCOPE_KERNEL = "kernel"          # the pallas_call, its tile pads, slice
 SCOPE_RESIDUAL = "residual"      # a block's add and ReLU
 SCOPES = (SCOPE_IM2COL, SCOPE_POOL, SCOPE_ACT_QUANT, SCOPE_KERNEL,
@@ -96,16 +102,13 @@ SCOPES = (SCOPE_IM2COL, SCOPE_POOL, SCOPE_ACT_QUANT, SCOPE_KERNEL,
 class PimConfig:
     """Operating point of the PIM engine.
 
-    Route selection is by substrate name: ``substrate`` is one of the
-    registry keys in :mod:`repro.engine.substrates` (``exact-pallas``,
-    ``exact-jnp``, ``analog``, ``analog-pallas``, ``emulate``). The
-    historical boolean pair (``analog`` + ``use_pallas``) is kept as a
-    deprecated alias and is resolved to a substrate name by
-    :attr:`resolved_substrate`.
+    ``substrate`` names the route: one of the registry keys in
+    :mod:`repro.engine.substrates` (``exact-pallas``, ``exact-jnp``,
+    ``analog``, ``analog-pallas``, ``emulate``). The cell density the
+    datapath assumes is :attr:`repro.core.arch.OpimaArch.cell_bits`.
     """
     weight_bits: int = 4          # paper baseline: 4b (one cell per weight)
     act_bits: int = 4
-    cell_bits: int = 4            # OPCM MLC density
     adc_bits: int = 5             # aggregation-unit ADC resolution
     wdm_chunk: int = 8            # products summed IN ANALOG before one ADC
                                   # conversion. OPIMA uses wavelength-specific
@@ -113,13 +116,9 @@ class PimConfig:
                                   # accumulates only across the subarrays of a
                                   # group sharing a wavelength (≈ kernel rows),
                                   # not across the full K dimension.
-    substrate: Optional[str] = None  # registry key; None -> resolve from the
-                                     # deprecated boolean pair below
-    analog: bool = False          # DEPRECATED: use substrate="analog"
+    substrate: str = EXACT_PALLAS  # registry key of the route
     read_noise_sigma: float = 0.0  # relative transmission read noise; if 0
                                    # and analog, uses the cell-DSE implied one
-    use_pallas: bool = True       # DEPRECATED: substrate="exact-pallas" /
-                                  # "exact-jnp"
     interpret: Optional[bool] = None  # Pallas interpret mode; None ->
                                       # per-backend (interpreter off-TPU,
                                       # compiled Mosaic on TPU) via
@@ -143,30 +142,6 @@ class PimConfig:
     @property
     def act_planes(self) -> int:
         return num_nibbles(self.act_bits)
-
-    @property
-    def resolved_substrate(self) -> str:
-        """The substrate registry key this config selects.
-
-        An explicit ``substrate`` wins; otherwise the deprecated boolean
-        pair is resolved (``analog`` before ``use_pallas``, matching the
-        historical dispatch order) with a :class:`DeprecationWarning`.
-        """
-        if self.substrate is not None:
-            return self.substrate
-        if self.analog:
-            warnings.warn(
-                "PimConfig(analog=True) is deprecated; use "
-                "PimConfig(substrate='analog')", DeprecationWarning,
-                stacklevel=3)
-            return ANALOG
-        if not self.use_pallas:
-            warnings.warn(
-                "PimConfig(use_pallas=False) is deprecated; use "
-                "PimConfig(substrate='exact-jnp')", DeprecationWarning,
-                stacklevel=3)
-            return EXACT_JNP
-        return EXACT_PALLAS
 
 
 DEFAULT_PIM = PimConfig()
@@ -194,7 +169,7 @@ class Plan:
 
     @property
     def substrate(self) -> str:
-        return self.cfg.resolved_substrate
+        return self.cfg.substrate
 
     def dequantized(self) -> jax.Array:
         """Float weights implied by the programmed codes (emulation)."""
@@ -312,11 +287,6 @@ class ExpertStackedPlan(Plan):
         return cls(dense=children[0], num_experts=aux[0], shard=aux[1])
 
 
-# Backward-compatible names (pre-engine API).
-PlannedWeights = DensePlan
-PlannedDepthwiseWeights = DepthwisePlan
-
-
 # ---------------------------------------------------------------------------
 # Programming — the single place weight decomposition happens
 # ---------------------------------------------------------------------------
@@ -387,14 +357,6 @@ def prepare_expert_weights(w: jax.Array, cfg: PimConfig = DEFAULT_PIM
     return ExpertStackedPlan(dense=dense, num_experts=w.shape[0])
 
 
-def _coerce_plan(w_q: Union[DensePlan, QTensor], cfg: PimConfig
-                 ) -> DensePlan:
-    if isinstance(w_q, DensePlan):
-        return w_q
-    # Legacy QTensor callers: plan on the fly (decomposition per call).
-    return plan_from_qtensor(w_q, cfg)
-
-
 # ---------------------------------------------------------------------------
 # Exact math (bit-sliced integer datapath)
 # ---------------------------------------------------------------------------
@@ -434,24 +396,42 @@ def _check_widths(cfg: PimConfig) -> None:
             "int64/float accumulation path")
 
 
-def _quantize_activations(x2: jax.Array, cfg: PimConfig):
-    """Dynamic per-row activation quantization + nibble decomposition (the
-    MDL array re-tuning per driven vector). Returns (QTensor, planes)."""
-    a_q = quantize(x2, bits=cfg.act_bits, axis=(1,))
-    return a_q, to_nibbles(a_q.values, cfg.act_bits)       # (Pa, M, K)
+def quantize_activations(x2: jax.Array, plan: DensePlan, cfg: PimConfig
+                         ) -> Tuple[jax.Array, jax.Array]:
+    """Drive (M, K) float rows into ``plan``'s layout: per-row dynamic
+    quantization at ``cfg.act_bits`` (the MDL array re-tunes per driven
+    vector), nibble decomposition, and K zero-padded to the plan's
+    programmed K (the per-call half of the padding contract; the weight
+    half happened once at programming time). Returns the int8 planes
+    (Pa, M, Kp) and the f32 per-row scales (M, 1) that every integer
+    substrate's drive takes."""
+    with jax.named_scope(SCOPE_ACT_QUANT):
+        a_q = quantize(x2, bits=cfg.act_bits, axis=(1,))
+        ap = to_nibbles(a_q.values, cfg.act_bits)          # (Pa, M, K)
+        pad_k = plan.planes.shape[1] - plan.k
+        if pad_k:
+            ap = jnp.pad(ap, ((0, 0), (0, 0), (0, pad_k)))
+    return ap, a_q.scale
 
 
 # ---------------------------------------------------------------------------
 # ABFT verification (repro.reliability.abft does the checksum math; these
 # helpers adapt it to each substrate's intermediates and post the result)
 # ---------------------------------------------------------------------------
-def _abft_report_exact(rowsum: jax.Array, a_values: jax.Array,
+def _act_codes(ap: jax.Array, plan: DensePlan) -> jax.Array:
+    """The (M, K) activation codes that planes ``ap`` recombine to, at
+    the plan's logical K (the padded columns hold zero planes)."""
+    return from_nibbles(ap)[:, :plan.k]
+
+
+def _abft_report_exact(rowsum: jax.Array, ap: jax.Array,
                        plan: DensePlan, cfg: PimConfig) -> None:
     """Exact-substrate check: int32 accumulator row-sums against the
     checksum-column matvec (bit-exact, wraparound-safe)."""
     from repro.reliability import abft as abft_mod
-    viol = abft_mod.int_violations(rowsum, a_values, plan.abft, plan.scale,
-                                   mode=cfg.verify, tag=cfg.abft_tag)
+    viol = abft_mod.int_violations(rowsum, _act_codes(ap, plan), plan.abft,
+                                   plan.scale, mode=cfg.verify,
+                                   tag=cfg.abft_tag)
     abft_mod.report(cfg.abft_tag, viol)
 
 
@@ -470,8 +450,9 @@ def _abft_report_float(out: jax.Array, expected: jax.Array, extra_tol,
     abft_mod.report(cfg.abft_tag, viol)
 
 
-def _analog_rowsum_tolerance(a_q: QTensor, plan: DensePlan, cfg: PimConfig,
-                             chunk: int, sigma: float) -> jax.Array:
+def _analog_rowsum_tolerance(a_scale: jax.Array, plan: DensePlan,
+                             cfg: PimConfig, chunk: int, sigma: float
+                             ) -> jax.Array:
     """Static upper bound on the analog readout's row-sum error: per-ADC
     rounding (half an LSB at the worst-case full scale chunk*15^2) plus a
     6-sigma transmission-noise margin, accumulated over chunks and plane
@@ -490,47 +471,41 @@ def _analog_rowsum_tolerance(a_q: QTensor, plan: DensePlan, cfg: PimConfig,
     per_col = n_chunks * s16 * (0.5 * lsb_bound
                                 + 6.0 * sigma * digit_max ** 2
                                 * chunk ** 0.5)
-    return a_q.scale[:, 0] * jnp.abs(plan.scale).sum() * per_col
+    return a_scale[:, 0] * jnp.abs(plan.scale).sum() * per_col
 
 
-def _abft_report_analog(out: jax.Array, a_q: QTensor, plan: DensePlan,
-                        cfg: PimConfig, chunk: int, sigma: float,
-                        bias: Optional[jax.Array]) -> None:
-    expected = a_q.scale[:, 0] * (
-        a_q.values.astype(jnp.float32) @ plan.abft["col_f32"])
+def _abft_report_analog(out: jax.Array, ap: jax.Array, a_scale: jax.Array,
+                        plan: DensePlan, cfg: PimConfig, chunk: int,
+                        sigma: float, bias: Optional[jax.Array]) -> None:
+    expected = a_scale[:, 0] * (
+        _act_codes(ap, plan).astype(jnp.float32) @ plan.abft["col_f32"])
     if bias is not None:
         expected = expected + bias.astype(jnp.float32).sum()
     _abft_report_float(out, expected,
-                       _analog_rowsum_tolerance(a_q, plan, cfg, chunk,
+                       _analog_rowsum_tolerance(a_scale, plan, cfg, chunk,
                                                 sigma), plan, cfg)
 
 
-def exact_jnp_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
-                       bias: Optional[jax.Array] = None,
-                       verify: bool = False) -> jax.Array:
+# ---------------------------------------------------------------------------
+# The integer substrates' drives: each takes the (Pa, M, Kp) planes and
+# (M, 1) scales of quantize_activations (or of a conv's im2col planes)
+# ---------------------------------------------------------------------------
+def exact_jnp_planes_matmul2d(ap: jax.Array, a_scale: jax.Array,
+                              plan: DensePlan, cfg: PimConfig,
+                              bias: Optional[jax.Array] = None,
+                              verify: bool = False) -> jax.Array:
     """``exact-jnp`` substrate: integer plane matmuls + shift-and-add in
     plain jnp, dequantized eagerly. Bit-identical to the Pallas route
     without a bias; the kernel's fused bias contracts mul+add to an FMA
     (one rounding) and may differ from this two-step add by 1 ulp."""
-    a_q, a_planes = _quantize_activations(x2, cfg)
-    w_planes = plan.planes[:, :plan.k, :plan.n]
-    acc = _shift_add(_plane_matmuls(a_planes, w_planes))
+    acc = _shift_add(_plane_matmuls(ap[:, :, :plan.k],
+                                    plan.planes[:, :plan.k, :plan.n]))
     if verify:
-        _abft_report_exact(acc.sum(axis=1), a_q.values, plan, cfg)
-    out = acc.astype(jnp.float32) * a_q.scale * plan.scale
+        _abft_report_exact(acc.sum(axis=1), ap, plan, cfg)
+    out = acc.astype(jnp.float32) * a_scale * plan.scale
     if bias is not None:
         out = out + bias.astype(jnp.float32).reshape(1, -1)
     return out
-
-
-def _pad_act_planes(a_planes: jax.Array, plan: DensePlan) -> jax.Array:
-    """Pad dynamic activation planes out to the plan's pre-padded K — the
-    per-call half of the padding contract every kernel substrate shares
-    (the weight half happened once at programming time)."""
-    pad_k = plan.planes.shape[1] - plan.k
-    if pad_k:
-        a_planes = jnp.pad(a_planes, ((0, 0), (0, 0), (0, pad_k)))
-    return a_planes
 
 
 def _pad_bias(bias: Optional[jax.Array], plan: DensePlan
@@ -606,60 +581,20 @@ def _log_kernel_call(m: int, pa: int, plan: DensePlan,
         bn=bn, bk=bk))
 
 
-def exact_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
-                          bias: Optional[jax.Array] = None,
-                          verify: bool = False) -> jax.Array:
-    """``exact-pallas`` substrate: per-row activation quantization, then
-    the Pallas kernel with the fused dequant epilogue
-    (:func:`exact_pallas_planes_matmul2d`)."""
-    with jax.named_scope(SCOPE_ACT_QUANT):
-        a_q, a_planes = _quantize_activations(x2, cfg)
-        ap = _pad_act_planes(a_planes, plan)
-    return exact_pallas_planes_matmul2d(ap, a_q.scale, plan, cfg, bias,
-                                        verify=verify, a_values=a_q.values)
-
-
 def exact_pallas_planes_matmul2d(ap: jax.Array, a_scale: jax.Array,
                                  plan: DensePlan, cfg: PimConfig,
                                  bias: Optional[jax.Array] = None,
-                                 verify: bool = False,
-                                 a_values: Optional[jax.Array] = None
-                                 ) -> jax.Array:
-    """The kernel half of ``exact-pallas``, for activations quantized
-    already: ``ap`` (Pa, M, Kp) int8 planes padded to the plan's K,
-    ``a_scale`` (M, 1) f32 per-row scales. The kernel's fused epilogue
+                                 verify: bool = False) -> jax.Array:
+    """``exact-pallas`` substrate: the Pallas kernel whose fused epilogue
     applies per-row act-scale × per-col weight-scale + optional bias on
     the int32 accumulator tile in VMEM. With ``verify`` the kernel also
     returns the int32 accumulator row-sums from the epilogue for the ABFT
-    check against ``a_values``, the (M, K) codes at logical K (padded
-    columns hold zero planes, so the padded row-sum equals the logical
-    one).
-
-    Interpret-mode verify takes the raw integer kernel plus a jnp
-    epilogue instead: the interpreter charges per grid-step ref traffic,
-    so the extra row-sum output costs ~9% there while the raw kernel
-    (two inputs, one output) plus an out-of-kernel dequant is ~3% — and
-    the accumulator, row-sum, and dequantized output are bit-identical
-    between the two routes (same modular int32 sums, same float
-    expression order). Compiled TPU keeps the fused epilogue, where the
-    row-sum rides the accumulator tile already in VMEM."""
+    check (padded columns hold zero planes, so the padded row-sum equals
+    the logical one)."""
     from repro.kernels.pim_matmul import ops as pim_ops
-    from repro.kernels.runtime import resolve_interpret
     _check_widths(cfg)
-    raw = verify and resolve_interpret(cfg.interpret)
     with jax.named_scope(SCOPE_KERNEL):
-        _log_kernel_call(ap.shape[1], ap.shape[0], plan,
-                         want_rowsum=verify and not raw)
-        if raw:
-            acc = pim_ops.pim_matmul_int(ap, plan.planes,
-                                         interpret=cfg.interpret)
-            _abft_report_exact(acc.sum(axis=1, dtype=jnp.int32), a_values,
-                               plan, cfg)
-            out = acc.astype(jnp.float32) * a_scale * plan.padded_scale
-            pb = _pad_bias(bias, plan)
-            if pb is not None:
-                out = out + pb
-            return out[:, :plan.n]
+        _log_kernel_call(ap.shape[1], ap.shape[0], plan, want_rowsum=verify)
         res = pim_ops.pim_matmul_fused(ap, plan.planes, a_scale,
                                        plan.padded_scale,
                                        bias=_pad_bias(bias, plan),
@@ -667,7 +602,7 @@ def exact_pallas_planes_matmul2d(ap: jax.Array, a_scale: jax.Array,
                                        want_rowsum=verify)
         if verify:
             out, rowsum = res
-            _abft_report_exact(rowsum, a_values, plan, cfg)
+            _abft_report_exact(rowsum, ap, plan, cfg)
             return out[:, :plan.n]
         return res[:, :plan.n]
 
@@ -715,53 +650,51 @@ def _resolve_analog_sigma(cfg: PimConfig, rng: Optional[jax.Array]
     return sigma
 
 
-def _analog_inputs(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
-                   rng: Optional[jax.Array]):
-    """Shared analog-substrate prep: dynamic activation quantization,
-    act-plane padding to the plan's stationary layout, the WDM chunk
-    length, and the resolved noise sigma."""
-    a_q, a_planes = _quantize_activations(x2, cfg)
-    # wdm_chunk <= 0 means "one chunk spans all of K" — same fallback the
-    # programming-time chunk padding uses
-    chunk = min(cfg.wdm_chunk, plan.k) if cfg.wdm_chunk > 0 else plan.k
-    return (a_q, _pad_act_planes(a_planes, plan), chunk,
-            _resolve_analog_sigma(cfg, rng))
+def _analog_chunk(plan: DensePlan, cfg: PimConfig) -> int:
+    """The WDM chunk length; ``wdm_chunk <= 0`` means one chunk spans all
+    of K, the same fallback the programming-time chunk padding uses."""
+    return min(cfg.wdm_chunk, plan.k) if cfg.wdm_chunk > 0 else plan.k
 
 
-def analog_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
-                    bias: Optional[jax.Array] = None,
-                    rng: Optional[jax.Array] = None,
-                    verify: bool = False) -> jax.Array:
+def analog_planes_matmul2d(ap: jax.Array, a_scale: jax.Array,
+                           plan: DensePlan, cfg: PimConfig,
+                           bias: Optional[jax.Array] = None,
+                           rng: Optional[jax.Array] = None,
+                           verify: bool = False) -> jax.Array:
     """``analog`` substrate: the whole-array jnp readout oracle — it
     materializes the full (planes, chunks, M, N) chunk-sum tensor, which
     makes it the slow-but-transparent accuracy-study twin of
     ``analog-pallas``."""
     from repro.kernels.analog_readout.ref import analog_readout_fused_ref
-    a_q, a_planes, chunk, sigma = _analog_inputs(x2, plan, cfg, rng)
+    chunk = _analog_chunk(plan, cfg)
+    sigma = _resolve_analog_sigma(cfg, rng)
     sigma_eff = sigma if rng is not None else 0.0
     out = analog_readout_fused_ref(
-        a_planes, plan.planes, a_q.scale, plan.padded_scale, chunk,
+        ap, plan.planes, a_scale, plan.padded_scale, chunk,
         cfg.adc_bits, sigma=sigma_eff, rng=rng
     )[:, :plan.n]
     if bias is not None:
         out = out + bias.astype(jnp.float32).reshape(1, -1)
     if verify:
-        _abft_report_analog(out, a_q, plan, cfg, chunk, sigma_eff, bias)
+        _abft_report_analog(out, ap, a_scale, plan, cfg, chunk, sigma_eff,
+                            bias)
     return out
 
 
-def analog_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
-                           bias: Optional[jax.Array] = None,
-                           rng: Optional[jax.Array] = None,
-                           verify: bool = False) -> jax.Array:
+def analog_pallas_planes_matmul2d(ap: jax.Array, a_scale: jax.Array,
+                                  plan: DensePlan, cfg: PimConfig,
+                                  bias: Optional[jax.Array] = None,
+                                  rng: Optional[jax.Array] = None,
+                                  verify: bool = False) -> jax.Array:
     """``analog-pallas`` substrate: the fused Pallas analog-readout kernel
     — chunked PD sums, optional threaded-key transmission noise, shared
     auto-ranged ADC, integer code accumulation, and the recombination/
     dequant epilogue all in VMEM tiles. Bit-identical to
-    :func:`analog_matmul2d` on the deterministic (``rng=None``) path;
-    statistically consistent under noise (different PRNG streams)."""
+    :func:`analog_planes_matmul2d` on the deterministic (``rng=None``)
+    path; statistically consistent under noise (different PRNG streams)."""
     from repro.kernels.analog_readout import ops as analog_ops
-    a_q, a_planes, chunk, sigma = _analog_inputs(x2, plan, cfg, rng)
+    chunk = _analog_chunk(plan, cfg)
+    sigma = _resolve_analog_sigma(cfg, rng)
     seed = None
     if rng is not None:
         # threaded key: the kernel folds grid coordinates into this seed
@@ -770,12 +703,13 @@ def analog_pallas_matmul2d(x2: jax.Array, plan: DensePlan, cfg: PimConfig,
                                   dtype=jnp.int32)
     sigma_eff = sigma if rng is not None else 0.0
     out = analog_ops.analog_matmul_fused(
-        a_planes, plan.planes, a_q.scale, plan.padded_scale, seed,
+        ap, plan.planes, a_scale, plan.padded_scale, seed,
         _pad_bias(bias, plan), chunk=chunk, adc_bits=cfg.adc_bits,
         sigma=sigma_eff, interpret=cfg.interpret)
     out = out[:, :plan.n]
     if verify:
-        _abft_report_analog(out, a_q, plan, cfg, chunk, sigma_eff, bias)
+        _abft_report_analog(out, ap, a_scale, plan, cfg, chunk, sigma_eff,
+                            bias)
     return out
 
 
@@ -837,69 +771,6 @@ def depthwise_emulate_matmul(x: jax.Array, plan: DepthwisePlan,
     dequantized per-channel filters."""
     return jnp.einsum("...kc,kc->...c", x.astype(jnp.float32),
                       plan.dequantized())
-
-
-# ---------------------------------------------------------------------------
-# Legacy entry points (dispatch through repro.engine)
-# ---------------------------------------------------------------------------
-def pim_matmul(x: jax.Array, w_q: Union[DensePlan, QTensor],
-               cfg: Optional[PimConfig] = None,
-               rng: Optional[jax.Array] = None,
-               act_scale_axis: int = -1,
-               bias: Optional[jax.Array] = None) -> jax.Array:
-    """Matrix multiply through the OPIMA PIM datapath (legacy wrapper).
-
-    Dispatches through the substrate registry in :mod:`repro.engine`; the
-    route is named by ``(cfg or plan.cfg).resolved_substrate``. New code
-    should call ``engine.matmul(x, plan)`` directly.
-
-    Args:
-      x: float activations, shape (..., K).
-      w_q: planned weights (K, N) from :func:`prepare_weights` (a legacy
-        :class:`QTensor` is planned on the fly).
-      cfg: PIM operating point; defaults to the plan's own config.
-      rng: PRNG key for the ``analog`` substrate's stochastic read noise
-        (``None`` -> deterministic ADC-only readout).
-      act_scale_axis: axis for dynamic activation scales (per-row default).
-      bias: optional (N,) float bias, applied inside the kernel's fused
-        epilogue on the Pallas path (after dequantization on all paths).
-
-    Returns:
-      float32 result of shape (..., N), de-quantized (+ bias).
-    """
-    if cfg is None:
-        cfg = w_q.cfg if isinstance(w_q, Plan) else DEFAULT_PIM
-        plan = _coerce_plan(w_q, cfg)
-        if cfg.weight_bits != plan.bits:
-            # adopted QTensor codes define the weight width when the
-            # caller gave no cfg; an *explicit* mismatched cfg still
-            # trips engine.matmul's consistency guard below
-            cfg = dataclasses.replace(cfg, weight_bits=plan.bits)
-    else:
-        plan = _coerce_plan(w_q, cfg)
-    from repro.engine import api as _engine_api
-    return _engine_api.matmul(x, plan, cfg=cfg, bias=bias, rng=rng)
-
-
-def pim_depthwise_matmul(x: jax.Array,
-                         w_q: Union[DepthwisePlan, jax.Array],
-                         cfg: Optional[PimConfig] = None) -> jax.Array:
-    """Grouped (depthwise) convolution (legacy wrapper; see
-    :func:`depthwise_exact_matmul`). x: (..., K, C) -> (..., C)."""
-    if not isinstance(w_q, DepthwisePlan):
-        w_q = prepare_depthwise_weights(w_q, cfg or DEFAULT_PIM)
-    if cfg is None:
-        cfg = w_q.cfg
-    from repro.engine import api as _engine_api
-    return _engine_api.matmul(x, w_q, cfg=cfg)
-
-
-def pim_linear(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
-               cfg: PimConfig = DEFAULT_PIM,
-               rng: Optional[jax.Array] = None) -> jax.Array:
-    """Float-weight convenience wrapper: plan on-the-fly + PIM matmul with
-    the bias fused into the kernel epilogue."""
-    return pim_matmul(x, prepare_weights(w, cfg), cfg, rng, bias=b)
 
 
 def reference_quantized_matmul(x: jax.Array,

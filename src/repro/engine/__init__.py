@@ -14,6 +14,12 @@ shaped the same way:
   y    = engine.matmul(x, plan)          # execute many — route comes from
                                          #   the plan, no mode flags
 
+``engine.matmul`` quantizes a dense plan's activations once
+(:func:`repro.core.pim.quantize_activations`: per-row scale, nibble
+planes, K padded to the plan) and hands the planes to the substrate's
+drive. ``engine.matmul_planes(ap, a_scale, plan)`` takes planes its
+caller built itself, as the CNN executor's im2col does.
+
 Substrates (string-keyed registry, :mod:`repro.engine.substrates`):
 ``exact-pallas`` (default; fused-epilogue Pallas kernel, bit-exact),
 ``exact-jnp`` (same math in jnp, bit-identical), ``analog``
@@ -33,7 +39,7 @@ from repro.core.pim import (DEFAULT_PIM, DensePlan, DepthwisePlan,
                             ExpertStackedPlan, PimConfig, Plan,
                             prepare_depthwise_weights, prepare_expert_weights,
                             prepare_weights, reference_quantized_matmul)
-from repro.engine.api import matmul, program
+from repro.engine.api import matmul, matmul_planes, program
 from repro.engine.mesh import (PlanShard, replicate, shard_plan,
                                shard_plan_tree)
 from repro.engine.persist import (PlanCorruptionError, load_plans,
@@ -47,7 +53,7 @@ from repro.engine.substrates import (AnalogPallasSubstrate, AnalogSubstrate,
 __all__ = [
     "DEFAULT_PIM", "PimConfig",
     "Plan", "DensePlan", "DepthwisePlan", "ExpertStackedPlan",
-    "program", "matmul",
+    "program", "matmul", "matmul_planes",
     "prepare_weights", "prepare_depthwise_weights", "prepare_expert_weights",
     "reference_quantized_matmul",
     "Substrate", "register_substrate", "get_substrate",

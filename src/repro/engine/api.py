@@ -1,9 +1,11 @@
 """Top-level engine verbs: ``program`` once, ``matmul`` many.
 
-The two functions here are the whole execution surface models see:
+These functions are the whole execution surface models see:
 
   plan = engine.program(w, cfg)        # weights -> stationary 'OPCM' plan
   y    = engine.matmul(x, plan)        # activations driven past the plan
+  y    = engine.matmul_planes(ap, a_scale, plan)
+                                       # activations quantized already
 
 ``program`` resolves the substrate from ``cfg`` (or an explicit override)
 and stamps it into the plan; ``matmul`` dispatches on the plan's recorded
@@ -32,7 +34,7 @@ def program(w: jax.Array, cfg: pim.PimConfig = pim.DEFAULT_PIM, *,
     Args:
       w: float weights — (K, N) for ``kind="dense"``, (K=kh*kw, C) for
         ``kind="depthwise"``, (E, K, N) for ``kind="experts"``.
-      cfg: PIM operating point; its ``resolved_substrate`` names the route
+      cfg: PIM operating point; its ``substrate`` names the route
         unless ``substrate`` overrides it.
       kind: which plan family to build.
       substrate: optional registry key overriding ``cfg``'s substrate.
@@ -49,7 +51,7 @@ def program(w: jax.Array, cfg: pim.PimConfig = pim.DEFAULT_PIM, *,
       A :class:`~repro.core.pim.Plan` carrying the substrate-stamped
       config (and, with ``mesh``, the stamped :class:`PlanShard`).
     """
-    sub = get_substrate(substrate or cfg.resolved_substrate)
+    sub = get_substrate(substrate or cfg.substrate)
     if kind == "dense":
         plan = sub.program(w, cfg)
     elif kind == "depthwise":
@@ -107,7 +109,22 @@ def matmul(x: jax.Array, plan: pim.Plan, *,
             f"was programmed at {plan.bits} bits; weight width is baked "
             "into the plan at programming time — build the override with "
             "dataclasses.replace(plan.cfg, ...) to change only the route")
-    sub = get_substrate(cfg.resolved_substrate)
+    sub = get_substrate(cfg.substrate)
     # operand-width guard runs inside Substrate.matmul, so direct
     # substrate calls are protected too
     return sub.matmul(x, plan, cfg=cfg, bias=bias, rng=rng, paired=paired)
+
+
+def matmul_planes(ap: jax.Array, a_scale: jax.Array, plan: pim.Plan, *,
+                  bias: Optional[jax.Array] = None,
+                  rng: Optional[jax.Array] = None) -> jax.Array:
+    """Drive activations that are quantized already past a plan, on the
+    plan's substrate: ``ap`` (Pa, M, Kp) int8 planes at the plan's padded
+    K and ``a_scale`` (M, 1) f32 per-row scales, the pair
+    :func:`repro.core.pim.quantize_activations` makes from float rows.
+    Returns (M, N) f32, bit-identical to ``matmul`` on the rows those
+    planes quantize. For a producer that writes the planes itself (a
+    conv's im2col); serves an unsharded ``DensePlan`` on a substrate with
+    an integer datapath and raises ``ValueError`` for anything else."""
+    return get_substrate(plan.substrate).matmul_planes(
+        ap, a_scale, plan, bias=bias, rng=rng)

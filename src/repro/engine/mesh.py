@@ -327,8 +327,7 @@ def _row_matmul(sub, x: jax.Array, plan: pim.DensePlan,
     mesh, axis = sh.mesh, sh.axis
     orig = x.shape
     x2 = x.reshape(-1, plan.k)
-    a_q, a_planes = pim._quantize_activations(x2, cfg)
-    a_planes = pim._pad_act_planes(a_planes, plan)      # (Pa, M, Kp)
+    a_planes, a_scale = pim.quantize_activations(x2, plan, cfg)
     b = _batch_axes(mesh, x2.shape[0])
     use_ref = sub.name == pim.EXACT_JNP
 
@@ -342,7 +341,7 @@ def _row_matmul(sub, x: jax.Array, plan: pim.DensePlan,
                     in_specs=(P(None, b, axis), P(None, axis, None)),
                     out_specs=P(b, None), check_vma=False
                     )(a_planes, plan.planes)
-    out = acc[:, :plan.n].astype(jnp.float32) * a_q.scale * plan.scale
+    out = acc[:, :plan.n].astype(jnp.float32) * a_scale * plan.scale
     if bias is not None:
         out = out + bias.astype(jnp.float32).reshape(1, -1)
     return out.reshape(orig[:-1] + (plan.n,))

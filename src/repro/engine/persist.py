@@ -78,8 +78,20 @@ def _leaf_spec(x) -> Dict[str, Any]:
     return {"shape": [int(d) for d in x.shape], "dtype": str(x.dtype)}
 
 
+# PimConfig fields that manifests written before their removal still
+# carry. None of them chose a stamped plan's route (``engine.program``
+# always stamps ``substrate``), so the loader drops them; any other
+# unknown field still fails.
+_RETIRED_CFG_KEYS = ("analog", "use_pallas", "cell_bits")
+
+
 def _cfg_spec(cfg: pim.PimConfig) -> Dict[str, Any]:
     return dataclasses.asdict(cfg)
+
+
+def _cfg_from_spec(spec: Dict[str, Any]) -> pim.PimConfig:
+    return pim.PimConfig(**{k: v for k, v in spec.items()
+                            if k not in _RETIRED_CFG_KEYS})
 
 
 def describe_plan_tree(tree: Any) -> Dict[str, Any]:
@@ -160,12 +172,12 @@ def build_plan_template(spec: Dict[str, Any]) -> Any:
         return pim.DensePlan(values=z[0], scale=z[1], planes=z[2],
                              padded_scale=z[3], bits=spec["bits"],
                              k=spec["k"], n=spec["n"],
-                             cfg=pim.PimConfig(**spec["cfg"]), abft=abft)
+                             cfg=_cfg_from_spec(spec["cfg"]), abft=abft)
     if kind == "depthwise-plan":
         z = [_zeros(l) for l in spec["leaves"]]
         return pim.DepthwisePlan(values=z[0], scale=z[1], planes=z[2],
                                  bits=spec["bits"],
-                                 cfg=pim.PimConfig(**spec["cfg"]))
+                                 cfg=_cfg_from_spec(spec["cfg"]))
     if kind == "dict":
         return {k: build_plan_template(v) for k, v in spec["items"].items()}
     if kind in ("list", "tuple"):

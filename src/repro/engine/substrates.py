@@ -36,6 +36,13 @@ plan type (:class:`~repro.core.pim.DensePlan`,
 :class:`~repro.core.pim.DepthwisePlan`,
 :class:`~repro.core.pim.ExpertStackedPlan`), so call sites need no
 shape-role flags either.
+
+A dense drive is split in two. :func:`repro.core.pim.quantize_activations`
+turns (M, K) float rows into (Pa, M, Kp) int8 planes and (M, 1) scales,
+once, for every integer substrate; each of those substrates implements
+only ``_drive``, which takes that pair. ``matmul_planes`` hands ``_drive``
+a pair its caller built itself (a conv's im2col planes). ``emulate`` has
+no integer datapath and keeps a float ``_dense2d`` of its own.
 """
 from __future__ import annotations
 
@@ -53,8 +60,9 @@ class Substrate:
     Subclasses set ``name`` (the registry key) and ``is_exact`` (whether
     ``matmul`` is bit-identical to
     :func:`repro.core.pim.reference_quantized_matmul`) and implement
-    ``_dense2d``. Plan-type dispatch (dense / depthwise / expert-stacked)
-    and activation reshaping are shared here.
+    ``_drive`` (or, without an integer datapath, ``_dense2d``). Plan-type
+    dispatch (dense / depthwise / expert-stacked), activation reshaping
+    and quantization are shared here.
     """
 
     name: str = ""
@@ -153,9 +161,42 @@ class Substrate:
             return jax.vmap(lambda d: run(x, d, None))(plan.dense)
         return jax.vmap(lambda d, key: run(x, d, key))(plan.dense, keys)
 
+    def matmul_planes(self, ap: jax.Array, a_scale: jax.Array,
+                      plan: pim.Plan, *, bias: Optional[jax.Array] = None,
+                      rng: Optional[jax.Array] = None) -> jax.Array:
+        """Drive activations quantized already past an unsharded
+        :class:`~repro.core.pim.DensePlan`: ``ap`` the (Pa, M, Kp) int8
+        planes at the plan's padded K, ``a_scale`` the (M, 1) f32 per-row
+        scales, as :func:`repro.core.pim.quantize_activations` makes
+        them. Returns (M, N) f32. Any other plan, or a substrate without
+        an integer datapath, raises ``ValueError``."""
+        if not self.integer_datapath:
+            raise ValueError(f"substrate {self.name!r} has no integer "
+                             "datapath to drive activation planes into")
+        if not isinstance(plan, pim.DensePlan):
+            raise ValueError("matmul_planes drives a DensePlan, got "
+                             f"{type(plan).__name__}")
+        if plan.shard is not None:
+            raise ValueError("matmul_planes drives an unsharded plan; "
+                             "this one is split over a mesh")
+        if ap.shape[-1] != plan.planes.shape[1]:
+            raise ValueError(f"activation planes are {ap.shape[-1]} deep, "
+                             f"the plan's K is {plan.planes.shape[1]}")
+        cfg = plan.cfg
+        pim._check_widths(cfg)
+        return self._drive(ap, a_scale, plan, cfg, bias, rng)
+
     def _dense2d(self, x2: jax.Array, plan: pim.DensePlan,
                  cfg: pim.PimConfig, bias: Optional[jax.Array],
                  rng: Optional[jax.Array]) -> jax.Array:
+        ap, a_scale = pim.quantize_activations(x2, plan, cfg)
+        return self._drive(ap, a_scale, plan, cfg, bias, rng)
+
+    def _drive(self, ap: jax.Array, a_scale: jax.Array,
+               plan: pim.DensePlan, cfg: pim.PimConfig,
+               bias: Optional[jax.Array],
+               rng: Optional[jax.Array]) -> jax.Array:
+        """(Pa, M, Kp) int8 planes and (M, 1) f32 scales -> (M, N) f32."""
         raise NotImplementedError
 
     @staticmethod
@@ -181,9 +222,9 @@ class ExactPallasSubstrate(Substrate):
     name = pim.EXACT_PALLAS
     is_exact = True
 
-    def _dense2d(self, x2, plan, cfg, bias, rng):
-        return pim.exact_pallas_matmul2d(x2, plan, cfg, bias,
-                                         verify=self._verify(plan, cfg))
+    def _drive(self, ap, a_scale, plan, cfg, bias, rng):
+        return pim.exact_pallas_planes_matmul2d(
+            ap, a_scale, plan, cfg, bias, verify=self._verify(plan, cfg))
 
 
 class ExactJnpSubstrate(Substrate):
@@ -192,9 +233,9 @@ class ExactJnpSubstrate(Substrate):
     name = pim.EXACT_JNP
     is_exact = True
 
-    def _dense2d(self, x2, plan, cfg, bias, rng):
-        return pim.exact_jnp_matmul2d(x2, plan, cfg, bias,
-                                      verify=self._verify(plan, cfg))
+    def _drive(self, ap, a_scale, plan, cfg, bias, rng):
+        return pim.exact_jnp_planes_matmul2d(
+            ap, a_scale, plan, cfg, bias, verify=self._verify(plan, cfg))
 
 
 class AnalogSubstrate(Substrate):
@@ -204,9 +245,10 @@ class AnalogSubstrate(Substrate):
     name = pim.ANALOG
     is_exact = False
 
-    def _dense2d(self, x2, plan, cfg, bias, rng):
-        return pim.analog_matmul2d(x2, plan, cfg, bias, rng,
-                                   verify=self._verify(plan, cfg))
+    def _drive(self, ap, a_scale, plan, cfg, bias, rng):
+        return pim.analog_planes_matmul2d(
+            ap, a_scale, plan, cfg, bias, rng,
+            verify=self._verify(plan, cfg))
 
 
 class AnalogPallasSubstrate(Substrate):
@@ -218,9 +260,10 @@ class AnalogPallasSubstrate(Substrate):
     name = pim.ANALOG_PALLAS
     is_exact = False
 
-    def _dense2d(self, x2, plan, cfg, bias, rng):
-        return pim.analog_pallas_matmul2d(x2, plan, cfg, bias, rng,
-                                          verify=self._verify(plan, cfg))
+    def _drive(self, ap, a_scale, plan, cfg, bias, rng):
+        return pim.analog_pallas_planes_matmul2d(
+            ap, a_scale, plan, cfg, bias, rng,
+            verify=self._verify(plan, cfg))
 
 
 class EmulateSubstrate(Substrate):

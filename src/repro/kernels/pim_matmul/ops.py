@@ -3,26 +3,21 @@
 ``pim_matmul_fused`` is the planned-weight entry point behind the engine's
 ``exact-pallas`` substrate (int32 accumulation + in-kernel dequant
 epilogue; see :mod:`repro.engine.substrates`); ``pim_matmul_int`` is the
-raw integer-plane entry point; ``pim_matmul_quantized`` is the end-to-end
-float API (quantize -> planes -> fused kernel -> float) for callers that
-hold raw codes. Model code should not call these directly — program a
-plan with ``engine.program`` and execute with ``engine.matmul`` so the
-route stays substrate-keyed.
+raw integer-plane entry point the mesh row split sums across devices.
+Model code should not call these directly — program a plan with
+``engine.program`` and execute with ``engine.matmul`` so the route stays
+substrate-keyed.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.pim_matmul.pim_matmul import (pim_matmul_fused_pallas,
                                                  pim_matmul_pallas)
 from repro.kernels.pim_matmul.ref import pim_matmul_fused_ref, pim_matmul_ref
 from repro.kernels.runtime import resolve_interpret
-from repro.quant.nibbles import to_nibbles
-from repro.quant.quantize import quantize
 
 
 def pim_matmul_int(a_planes: jax.Array, w_planes: jax.Array,
@@ -54,25 +49,3 @@ def pim_matmul_fused(a_planes: jax.Array, w_planes: jax.Array,
                                    bias,
                                    interpret=resolve_interpret(interpret),
                                    want_rowsum=want_rowsum)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("weight_bits", "act_bits", "interpret"))
-def pim_matmul_quantized(x: jax.Array, w_q_values: jax.Array,
-                         w_q_scale: jax.Array, weight_bits: int = 4,
-                         act_bits: int = 4,
-                         interpret: Optional[bool] = None
-                         ) -> jax.Array:
-    """Float (..., K) x quantized (K, N) -> float (..., N) via the fused
-    kernel. Callers that execute repeatedly should use the engine's
-    ``prepare_weights`` instead so the plane decomposition happens once."""
-    orig = x.shape
-    n = w_q_values.shape[-1]
-    x2 = x.reshape(-1, orig[-1])
-    a_q = quantize(x2, bits=act_bits, axis=(1,))
-    a_planes = to_nibbles(a_q.values, act_bits)
-    w_planes = to_nibbles(w_q_values, weight_bits)
-    w_scale = jnp.broadcast_to(w_q_scale.astype(jnp.float32), (1, n))
-    out = pim_matmul_fused_pallas(a_planes, w_planes, a_q.scale, w_scale,
-                                  interpret=resolve_interpret(interpret))
-    return out.reshape(orig[:-1] + (n,))

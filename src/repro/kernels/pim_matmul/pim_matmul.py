@@ -1,7 +1,7 @@
 """Pallas TPU kernel: OPIMA bit-sliced (nibble-plane) integer matmul.
 
-This is the paper's PIM datapath adapted to the TPU memory hierarchy
-(DESIGN.md §2): weight nibbles live in VMEM tiles (the "subarray"), each
+This is the paper's PIM datapath adapted to the TPU memory hierarchy:
+weight nibbles live in VMEM tiles (the "subarray"), each
 (act-plane, weight-plane) pair is a one-shot MXU matmul over the K block
 (the "WDM accumulation"), and the shift-and-add recombination (the
 "aggregation unit") happens in the int32 VMEM accumulator.
@@ -256,15 +256,13 @@ def pim_matmul_pallas(a_planes: jax.Array, w_planes: jax.Array,
 
 def _pim_matmul_fused_kernel(a_ref, w_ref, as_ref, ws_ref, *rest, n_k: int,
                              pa: int, pw: int, has_bias: bool,
-                             lane_pad: bool, want_rowsum: bool):
+                             want_rowsum: bool):
     """One (m, n, k) grid step with the fused dequant epilogue.
 
     a_ref: (Pa, bm, bk) int8  — activation nibble planes tile
     w_ref: (Pw, bk, bn) int8  — weight nibble planes tile
     as_ref: (bm, LANE) f32    — per-row activation scales, value in lane 0
-                                ((bm, 1) when lane_pad=False)
     ws_ref: (SUBLANE, bn) f32 — per-column weight scales, value in row 0
-                                ((1, bn) when lane_pad=False)
     [b_ref]                   — optional bias, same layout as ws_ref
     o_ref: (bm, bn) f32       — dequantized output tile (last k step)
     [rs_ref]: (bm, LANE) i32  — this (i, j) tile's accumulator row-sum
@@ -278,10 +276,6 @@ def _pim_matmul_fused_kernel(a_ref, w_ref, as_ref, ws_ref, *rest, n_k: int,
                                 (~13% whole-kernel tax measured on the
                                 revisited form).
     acc_ref: (bm, bn) int32   — VMEM accumulator scratch
-
-    ``lane_pad`` selects the register-tile-aligned scale layout; only the
-    slice read in the epilogue differs — arithmetic is identical, and the
-    parity test pins the two layouts bit-for-bit against each other.
     """
     rest = list(rest)
     b_ref = rest.pop(0) if has_bias else None
@@ -305,21 +299,17 @@ def _pim_matmul_fused_kernel(a_ref, w_ref, as_ref, ws_ref, *rest, n_k: int,
             # same value so the caller can read lane 0 without a relayout
             tile_rs = jnp.sum(acc_ref[...], axis=1, keepdims=True)
             rs_ref[...] = jnp.broadcast_to(tile_rs, rs_ref.shape)
-        if lane_pad:
-            a_s = as_ref[...][:, :1]        # (bm, 1): value lives in lane 0
-            w_s = ws_ref[...][:1, :]        # (1, bn): value lives in row 0
-        else:
-            a_s = as_ref[...]
-            w_s = ws_ref[...]
+        a_s = as_ref[...][:, :1]            # (bm, 1): value lives in lane 0
+        w_s = ws_ref[...][:1, :]            # (1, bn): value lives in row 0
         out = acc_ref[...].astype(jnp.float32) * a_s * w_s
         if has_bias:
-            out = out + (b_ref[...][:1, :] if lane_pad else b_ref[...])
+            out = out + b_ref[...][:1, :]
         o_ref[...] = out
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret",
-                                    "lane_pad", "want_rowsum"))
+                                    "want_rowsum"))
 def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
                             a_scale: jax.Array, w_scale: jax.Array,
                             bias: Optional[jax.Array] = None,
@@ -327,7 +317,6 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
                             bn: Optional[int] = None,
                             bk: Optional[int] = None,
                             interpret: bool = False,
-                            lane_pad: bool = True,
                             want_rowsum: bool = False):
     """Bit-sliced integer matmul with the fused dequantization epilogue.
 
@@ -339,10 +328,6 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
       bias: optional (1, N) f32, added after dequantization.
       bm/bn/bk: VMEM tile sizes; ``kernel_tiles`` picks those not given.
       interpret: run in interpreter mode (CPU validation).
-      lane_pad: pad the width-1 scale vectors to full (SUBLANE, LANE)
-        register tiles so compiled Mosaic lowering is clean (default).
-        ``False`` keeps the legacy width-1 BlockSpecs — interpret-mode
-        only, retained as the parity baseline for tests.
       want_rowsum: also emit the (M,) int32 accumulator row-sums from
         the epilogue (ABFT checksum verification input). Zero-padded
         columns contribute nothing, so the row-sum over the padded tile
@@ -368,21 +353,15 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
     n_k = kp // bk
     has_bias = bias is not None
 
-    if lane_pad:
-        # scale vectors padded (with zeros) to full register tiles; the
-        # epilogue reads only lane 0 / sublane 0, so values are unchanged
-        a_scale = jnp.pad(a_scale, ((0, 0), (0, LANE - 1)))
-        w_scale = jnp.pad(w_scale, ((0, SUBLANE - 1), (0, 0)))
-        if has_bias:
-            bias = jnp.pad(bias, ((0, SUBLANE - 1), (0, 0)))
-        as_spec = pl.BlockSpec((bm, LANE), lambda i, j, s: (i, 0))
-        ws_spec = pl.BlockSpec((SUBLANE, bn), lambda i, j, s: (0, j))
-    else:
-        # legacy width-1 scale specs, kept for interpret-mode parity
-        # tests; compiled Mosaic uses the lane_pad branch above
-        # repro-lint: disable=RPR401
-        as_spec = pl.BlockSpec((bm, 1), lambda i, j, s: (i, 0))
-        ws_spec = pl.BlockSpec((1, bn), lambda i, j, s: (0, j))
+    # scale vectors padded (with zeros) to full register tiles, which
+    # compiled Mosaic needs; the epilogue reads only lane 0 / sublane 0,
+    # so values are unchanged
+    a_scale = jnp.pad(a_scale, ((0, 0), (0, LANE - 1)))
+    w_scale = jnp.pad(w_scale, ((0, SUBLANE - 1), (0, 0)))
+    if has_bias:
+        bias = jnp.pad(bias, ((0, SUBLANE - 1), (0, 0)))
+    as_spec = pl.BlockSpec((bm, LANE), lambda i, j, s: (i, 0))
+    ws_spec = pl.BlockSpec((SUBLANE, bn), lambda i, j, s: (0, j))
 
     in_specs = [
         pl.BlockSpec((pa, bm, bk), lambda i, j, s: (0, i, s)),
@@ -408,8 +387,7 @@ def pim_matmul_fused_pallas(a_planes: jax.Array, w_planes: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_pim_matmul_fused_kernel, n_k=n_k, pa=pa, pw=pw,
-                          has_bias=has_bias, lane_pad=lane_pad,
-                          want_rowsum=want_rowsum),
+                          has_bias=has_bias, want_rowsum=want_rowsum),
         grid=(pl.cdiv(m, bm), np_ // bn, n_k),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -35,8 +35,6 @@ selected by substrate name, one of :func:`repro.engine.available_substrates`:
                                  physically-faithful mode at serving
                                  speed (bit-identical to analog here)
   --pim-substrate emulate        weight-quantization-only float matmul
-                                 (the historical --pim-emulate behaviour,
-                                 now a first-class substrate)
 
 Weights the engine does not cover yet (SSM projections, embedding tables)
 keep the fake-quantize emulation so every substrate still models their
@@ -60,7 +58,6 @@ import json
 import os
 import pathlib
 import time
-import warnings
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -70,7 +67,7 @@ import numpy as np
 from repro import engine
 from repro.configs.base import ModelConfig, get_config
 from repro.core.perfmodel import network_perf, total_power_w
-from repro.core.pim import PimConfig
+from repro.core.pim import EXACT_PALLAS, PimConfig
 from repro.core.workloads import DenseSpec
 from repro.models.lm import decode_step, init_lm, prefill
 from repro.quant.quantize import fake_quantize
@@ -109,7 +106,7 @@ def plan_params_for_pim(params, cfg: PimConfig):
     execution would compile every primitive separately and hold each
     full-size f32 temporary (quantize, STE, nibble split) in device
     memory at once."""
-    sub = engine.get_substrate(cfg.resolved_substrate)
+    sub = engine.get_substrate(cfg.substrate)
     fake_q = jax.jit(fake_quantize, static_argnames=("bits", "axis"))
 
     def _cfg_for(keys):
@@ -270,7 +267,7 @@ def _pim_params(params, cfg: ModelConfig, pim_cfg: PimConfig,
         return planned
     # the digest hashes every weight host-side, so only pay for it when a
     # plan checkpoint is actually in play
-    want = {"substrate": pim_cfg.resolved_substrate,
+    want = {"substrate": pim_cfg.substrate,
             "weight_bits": pim_cfg.weight_bits,
             "act_bits": pim_cfg.act_bits,
             "abft": pim_cfg.verify,
@@ -314,24 +311,6 @@ def _pim_params(params, cfg: ModelConfig, pim_cfg: PimConfig,
     return planned
 
 
-def _resolve_substrate(pim_substrate: Optional[str],
-                       pim_emulate: bool) -> str:
-    if pim_emulate:
-        # stacklevel: _resolve_substrate -> _setup -> serve* -> user
-        warnings.warn("pim_emulate is deprecated; use "
-                      "pim_substrate='emulate'", DeprecationWarning,
-                      stacklevel=4)
-        # None means "no explicit request" — any explicit substrate,
-        # including exact-pallas, conflicts with the deprecated flag
-        if pim_substrate not in (None, "emulate"):
-            raise ValueError(
-                "--pim-emulate (deprecated) conflicts with an explicit "
-                f"--pim-substrate {pim_substrate!r}; drop --pim-emulate "
-                "and pass --pim-substrate emulate instead")
-        return "emulate"
-    return pim_substrate or "exact-pallas"
-
-
 # Where the persistent compilation cache lives when the environment does
 # not name one: a fixed directory inside the checkout (the path is part of
 # the cache key, so a directory that moves never hits).
@@ -362,7 +341,7 @@ def setup_compile_cache() -> str:
 
 
 def _setup(arch: str, layers: Optional[int], d_model: Optional[int],
-           pim: bool, pim_bits: int, pim_emulate: bool,
+           pim: bool, pim_bits: int,
            pim_substrate: Optional[str], plan_dir: Optional[str],
            mesh_spec: Optional[str] = None,
            abft: str = "off"):
@@ -389,7 +368,7 @@ def _setup(arch: str, layers: Optional[int], d_model: Optional[int],
     elif layers:
         cfg = cfg.with_depth(layers)
     params = init_lm(cfg, jax.random.PRNGKey(0))
-    substrate = _resolve_substrate(pim_substrate, pim_emulate)
+    substrate = pim_substrate or EXACT_PALLAS
     pim_cfg = PimConfig(weight_bits=pim_bits, act_bits=pim_bits,
                         substrate=substrate, verify=abft)
     if pim:
@@ -422,15 +401,14 @@ def write_metrics_json(path: str, result: Dict[str, Any]) -> None:
 
 def serve(arch: str, batch: int = 2, prompt_len: int = 16, gen: int = 8,
           layers: Optional[int] = None, d_model: Optional[int] = None,
-          pim: bool = False, pim_bits: int = 4, pim_emulate: bool = False,
+          pim: bool = False, pim_bits: int = 4,
           greedy: bool = True, pim_substrate: Optional[str] = None,
           plan_dir: Optional[str] = None, mesh: Optional[str] = None,
           metrics_json: Optional[str] = None,
           stop_tokens: Sequence[int] = (),
           eos_token: Optional[int] = None) -> Dict[str, Any]:
     """Run one batched serve request; ``pim_substrate`` names the engine
-    route (default ``exact-pallas``; ``pim_emulate=True`` is the
-    deprecated spelling of ``pim_substrate="emulate"``). ``mesh`` is a
+    route (default ``exact-pallas``). ``mesh`` is a
     "dp,tp" device-mesh spec — the programmed plans are split over the
     mesh and the batch matmuls run tensor/expert-parallel.
 
@@ -442,7 +420,7 @@ def serve(arch: str, batch: int = 2, prompt_len: int = 16, gen: int = 8,
     the truncated prefix is exactly what the continuous engine emits for
     the same request."""
     cfg, params, substrate, pim_cfg, _ = _setup(
-        arch, layers, d_model, pim, pim_bits, pim_emulate, pim_substrate,
+        arch, layers, d_model, pim, pim_bits, pim_substrate,
         plan_dir, mesh_spec=mesh)
 
     rng = np.random.default_rng(0)
@@ -576,7 +554,7 @@ def serve_continuous(arch: str, num_slots: int = 4, num_requests: int = 16,
                      prompt_len: int = 16, gen: int = 8,
                      layers: Optional[int] = None,
                      d_model: Optional[int] = None, pim: bool = False,
-                     pim_bits: int = 4, pim_emulate: bool = False,
+                     pim_bits: int = 4,
                      pim_substrate: Optional[str] = None,
                      plan_dir: Optional[str] = None,
                      arrival_rate: float = 0.5,
@@ -636,7 +614,7 @@ def serve_continuous(arch: str, num_slots: int = 4, num_requests: int = 16,
                          "(without checksum verification the corruption "
                          "would go undetected)")
     cfg, params, substrate, pim_cfg, dev_mesh = _setup(
-        arch, layers, d_model, pim, pim_bits, pim_emulate, pim_substrate,
+        arch, layers, d_model, pim, pim_bits, pim_substrate,
         plan_dir, mesh_spec=mesh, abft=abft)
     if trace_file:
         requests = _load_trace(trace_file, cfg.vocab_size, seed)
@@ -792,8 +770,6 @@ def main() -> None:
                     choices=engine.available_substrates(),
                     help="engine substrate the programmed plans execute on "
                          "(default: exact-pallas)")
-    ap.add_argument("--pim-emulate", action="store_true",
-                    help="deprecated alias for --pim-substrate emulate")
     ap.add_argument("--plan-dir", default=None,
                     help="persist/restore programmed plans here so "
                          "restarts skip re-programming")
@@ -888,7 +864,6 @@ def main() -> None:
             num_requests=args.requests, prompt_len=args.prompt_len,
             gen=args.gen, layers=args.layers, d_model=args.d_model,
             pim=args.pim, pim_bits=args.pim_bits,
-            pim_emulate=args.pim_emulate,
             pim_substrate=args.pim_substrate, plan_dir=args.plan_dir,
             arrival_rate=args.arrival_rate, trace_file=args.trace_file,
             seed=args.seed, sync_every=args.sync_every, mesh=args.mesh,
@@ -932,7 +907,7 @@ def main() -> None:
     else:
         res = serve(args.arch, args.batch, args.prompt_len, args.gen,
                     args.layers, args.d_model, args.pim, args.pim_bits,
-                    args.pim_emulate, pim_substrate=args.pim_substrate,
+                    pim_substrate=args.pim_substrate,
                     plan_dir=args.plan_dir, mesh=args.mesh,
                     metrics_json=args.metrics_json,
                     stop_tokens=stop_tokens, eos_token=args.eos_token)

@@ -26,9 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from repro import engine
-from repro.core.pim import (EXACT_PALLAS, SCOPE_ACT_QUANT, SCOPE_IM2COL,
-                            SCOPE_POOL, SCOPE_RESIDUAL, DensePlan, PimConfig,
-                            exact_pallas_planes_matmul2d)
+from repro.core.pim import (SCOPE_ACT_QUANT, SCOPE_IM2COL, SCOPE_POOL,
+                            SCOPE_RESIDUAL, DensePlan, PimConfig)
 from repro.core.workloads import ConvSpec, DenseSpec, LayerSpec
 from repro.quant.nibbles import to_nibbles
 from repro.quant.quantize import fake_quantize, quantize, scale_from_amax
@@ -74,11 +73,12 @@ def _im2col(x: jax.Array, spec: ConvSpec) -> jax.Array:
         return jnp.concatenate(_taps(_same_pad(x, spec), spec), axis=-1)
 
 
-def _im2col_planes(x: jax.Array, spec: ConvSpec, plan: DensePlan,
-                   bits: int) -> Tuple[jax.Array, jax.Array]:
+def _im2col_planes(x: jax.Array, spec: ConvSpec, plan: DensePlan
+                   ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, H, W, C) -> the int8 planes (Pa, M, Kp) and the f32 scales
-    (M, 1) that per-row quantization of ``_im2col(x, spec)`` at ``bits``
-    gives, K padded to the plan's, with no float patch matrix between.
+    (M, 1) that ``quantize_activations`` of ``_im2col(x, spec)`` at the
+    plan's ``act_bits`` gives, K padded to the plan's, with no float
+    patch matrix between.
 
     A patch row's abs max is the max over its kh x kw window of each
     pixel's channel abs max on the padded map: the same maximum of the
@@ -86,6 +86,7 @@ def _im2col_planes(x: jax.Array, spec: ConvSpec, plan: DensePlan,
     quantizing the patches. Each tap is quantized and split into planes
     on its own, so the concatenate writes int8 planes only."""
     s, oh, ow = spec.stride, spec.out_h, spec.out_w
+    bits = plan.cfg.act_bits
     with jax.named_scope(SCOPE_IM2COL):
         xp = _same_pad(x, spec)
     with jax.named_scope(SCOPE_ACT_QUANT):
@@ -139,10 +140,18 @@ class _Executor:
     With ``pim`` set, every layer's weights are *programmed once* per
     executor through :func:`repro.engine.program` (quantize +
     nibble-decompose + pad at programming time, keyed on the deterministic
-    layer name) and every matmul drives activations past the stationary
-    plans via :func:`repro.engine.matmul` — the paper's weight-stationary
-    OPCM mapping on whichever substrate ``pim.resolved_substrate`` names.
-    The layer bias is fused into the kernel's dequant epilogue.
+    layer name), and activations are driven past the stationary plans on
+    whichever substrate ``pim.substrate`` names — the paper's
+    weight-stationary OPCM mapping. The layer bias is fused into the
+    kernel's dequant epilogue.
+
+    A dense conv on a substrate with an integer datapath (``exact-pallas``,
+    ``exact-jnp``, ``analog``, ``analog-pallas``) writes its patches as
+    int8 planes straight from the map (``_im2col_planes``) and drives them
+    through :func:`repro.engine.matmul_planes`. Without PIM, or on
+    ``emulate``, it builds the f32 patches (``_im2col``) and multiplies
+    them (:func:`repro.engine.matmul` on ``emulate``). Depthwise convs and
+    dense layers always take float rows.
     """
 
     def __init__(self, params: Params, quant_bits: int = 0,
@@ -171,32 +180,31 @@ class _Executor:
             return None
         return jax.random.fold_in(self.rng, zlib.crc32(name.encode()))
 
-    def matmul(self, x: jax.Array, w: jax.Array, per_col_axis, name: str,
-               bias: Optional[jax.Array] = None) -> jax.Array:
+    def _weight(self, w: jax.Array) -> jax.Array:
+        """A layer's (K, N) weights, fake-quantized per column when
+        ``quant_bits`` asks."""
         if self.quant_bits:
-            w = fake_quantize(w, self.quant_bits, axis=per_col_axis)
+            return fake_quantize(w, self.quant_bits, axis=(0,))
+        return w
+
+    def matmul(self, x: jax.Array, w: jax.Array, name: str,
+               bias: Optional[jax.Array] = None) -> jax.Array:
         if self.pim is not None:
             return engine.matmul(x, self._plan(name, w), cfg=self.pim,
                                  bias=bias, rng=self._layer_rng(name))
         y = x @ w
         return y if bias is None else y + bias
 
-    def _quantizes_patches(self) -> bool:
-        """Whether dense convs quantize as im2col expands: on the
-        exact-pallas substrate, unless an ABFT check wants the codes."""
-        return self.pim is not None and self.pim.verify == "off" and \
-            self.pim.resolved_substrate == EXACT_PALLAS
-
-    def _exact_conv(self, spec: ConvSpec, x: jax.Array, w: jax.Array,
+    def _dense_conv(self, spec: ConvSpec, x: jax.Array, w: jax.Array,
                     bias: jax.Array) -> jax.Array:
-        """A dense conv on exact-pallas: the int8 patch planes straight
-        from the map, then the kernel half of the route; bit-identical to
-        driving ``_im2col``'s patches through ``engine.matmul``."""
-        if self.quant_bits:
-            w = fake_quantize(w, self.quant_bits, axis=(0,))
-        plan = self._plan(spec.name, w)
-        ap, a_scale = _im2col_planes(x, spec, plan, self.pim.act_bits)
-        y = exact_pallas_planes_matmul2d(ap, a_scale, plan, self.pim, bias)
+        """A dense conv as one GEMM over its patch rows."""
+        plan = None if self.pim is None else self._plan(spec.name, w)
+        if plan is None or \
+                not engine.get_substrate(plan.substrate).integer_datapath:
+            return self.matmul(_im2col(x, spec), w, spec.name, bias=bias)
+        ap, a_scale = _im2col_planes(x, spec, plan)
+        y = engine.matmul_planes(ap, a_scale, plan, bias=bias,
+                                 rng=self._layer_rng(spec.name))
         return y.reshape(x.shape[0], spec.out_h, spec.out_w, spec.out_c)
 
     def conv(self, spec: ConvSpec, x: jax.Array, relu: bool = True
@@ -206,19 +214,15 @@ class _Executor:
                 x = _maxpool(x, x.shape[1] // spec.in_h)
             p = self.params[spec.name]
             if spec.groups == 1:
-                w = p["w"].reshape(-1, spec.out_c)
-                if self._quantizes_patches():
-                    y = self._exact_conv(spec, x, w, p["b"])
-                else:
-                    y = self.matmul(_im2col(x, spec), w, (0,), spec.name,
-                                    bias=p["b"])
+                y = self._dense_conv(
+                    spec, x, self._weight(p["w"].reshape(-1, spec.out_c)),
+                    p["b"])
             else:                                      # depthwise
                 cols = _im2col(x, spec)
                 b, oh, ow, _ = cols.shape
                 cols = cols.reshape(b, oh, ow, spec.kh * spec.kw, spec.in_c)
-                w = p["w"].reshape(spec.kh * spec.kw, spec.in_c)
-                if self.quant_bits:
-                    w = fake_quantize(w, self.quant_bits, axis=(0,))
+                w = self._weight(
+                    p["w"].reshape(spec.kh * spec.kw, spec.in_c))
                 if self.pim is not None:
                     # per-channel programmed plan through the bit-sliced engine
                     y = engine.matmul(cols,
@@ -233,8 +237,8 @@ class _Executor:
         with jax.named_scope(spec.name):
             if x.ndim == 4:
                 x = _dense_rows(x, spec)
-            y = self.matmul(x, self.params[spec.name]["w"], (0,), spec.name,
-                            bias=self.params[spec.name]["b"])
+            y = self.matmul(x, self._weight(self.params[spec.name]["w"]),
+                            spec.name, bias=self.params[spec.name]["b"])
             return jax.nn.relu(y) if relu else y
 
 

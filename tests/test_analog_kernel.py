@@ -256,7 +256,7 @@ def test_plan_persistence_round_trip(tmp_path):
     d = str(tmp_path / "plans")
     engine.save_plans(d, tree)
     restored, _, _ = engine.load_plans(d)
-    assert restored["dense"].cfg.resolved_substrate == "analog-pallas"
+    assert restored["dense"].cfg.substrate == "analog-pallas"
     assert jnp.array_equal(engine.matmul(x, tree["dense"]),
                            engine.matmul(x, restored["dense"]))
     assert jnp.array_equal(engine.matmul(x, tree["experts"]),

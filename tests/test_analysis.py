@@ -190,16 +190,6 @@ def test_rpr403_pallas_call_without_name():
     assert _hits(f) == [("RPR403", 4)]
 
 
-def test_rpr501_deprecated_aliases():
-    f = _lint("""\
-    def configure(cfg):
-        if cfg.use_pallas:
-            pass
-        return replace(cfg, analog=True)
-    """, assume_hot=False)
-    assert _hits(f) == [("RPR501", 2), ("RPR501", 4)]
-
-
 # ---------------------------------------------------------------------------
 # negatives: the sanctioned patterns stay quiet
 # ---------------------------------------------------------------------------
@@ -274,7 +264,7 @@ def test_select_and_ignore():
 def test_checker_registry():
     names = available_checkers()
     assert set(names) == {"host-sync", "recompile", "pytree",
-                          "pallas-tile", "deprecated"}
+                          "pallas-tile"}
     assert get_checker("host-sync").rules == ("RPR101", "RPR102")
     with pytest.raises(ValueError, match="unknown checker"):
         get_checker("nope")

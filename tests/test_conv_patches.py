@@ -1,12 +1,13 @@
-"""Dense convs on the exact-pallas route quantize as im2col expands.
+"""Dense convs on an integer substrate quantize as im2col expands.
 
 The executor takes each patch row's scale from the unexpanded map and
-writes the taps' int8 planes straight into the kernel's K layout
-(``models/cnn._im2col_planes``), then runs the kernel half of the route
-(``pim.exact_pallas_planes_matmul2d``). These tests hold it to the f32
-patch route it replaces (``_im2col``, then ``engine.matmul``): the same
-codes, scales and logits bit for bit, no float patch matrix in the
-program, the same kernel calls; and they hold the float entry the LM
+writes the taps' int8 planes straight into the plan's K layout
+(``models/cnn._im2col_planes``), then drives the plan with them
+(``engine.matmul_planes``). These tests hold it to the f32 patch route
+it replaces (``_im2col``, then ``engine.matmul``), on every integer
+substrate: the same codes, scales and outputs bit for bit, no float
+patch matrix in the program, the same kernel calls; they check that
+``emulate`` keeps the f32 patches; and they hold the float entry the LM
 calls to the program it compiled to before the split.
 
 Shapes are the benchmark configuration's rehearsal sizes
@@ -81,7 +82,7 @@ def test_conv_codes_and_output_match_f32_patches(name, bits):
     plan = engine.program(w.reshape(-1, spec.out_c), cfg)
     k, kp = plan.k, plan.planes.shape[1]
 
-    ap, scale = cnn._im2col_planes(x, spec, plan, bits)
+    ap, scale = cnn._im2col_planes(x, spec, plan)
     cols = cnn._im2col(x, spec).reshape(-1, k)
     a_q = quantize(cols, bits, axis=(1,))
     assert ap.dtype == jnp.int8 and ap.shape == (cfg.act_planes,
@@ -97,11 +98,79 @@ def test_conv_codes_and_output_match_f32_patches(name, bits):
 
     ex = cnn._Executor({name: {"w": w, "b": b}}, pim=cfg,
                        plans={name: plan})
-    assert ex._quantizes_patches()
     got = ex.conv(spec, x, relu=False)
     want = engine.matmul(cnn._im2col(x, spec), plan, cfg=cfg, bias=b)
     assert got.shape == want.shape
     np.testing.assert_array_equal(got, want)
+
+
+# small convs for the other substrates, which interpret mode runs slowly:
+# K 27 (3 channels) that the plan pads to the WDM chunk, and stride 2
+_SMALL = {"k27": ConvSpec("k27", 6, 6, 3, 16, 3, 3),
+          "k72_s2": ConvSpec("k72_s2", 6, 6, 8, 24, 3, 3, stride=2)}
+# (substrate, verify); analog noise stays off (no rng), so every route
+# is deterministic
+_INTEGER_ROUTES = [("exact-jnp", "off"), ("analog", "off"),
+                   ("analog-pallas", "off"), ("exact-pallas", "always")]
+
+
+def _small_conv(spec, cfg, seed):
+    kw_, kx, kb = jax.random.split(jax.random.PRNGKey(seed), 3)
+    w = jax.random.normal(kw_, (spec.kh, spec.kw, spec.in_c, spec.out_c))
+    b = jax.random.normal(kb, (spec.out_c,))
+    plan = engine.program(w.reshape(-1, spec.out_c), cfg)
+    ex = cnn._Executor({spec.name: {"w": w, "b": b}}, pim=cfg,
+                       plans={spec.name: plan})
+    return ex, plan, b, _map(spec, kx)
+
+
+@pytest.mark.parametrize("spec", list(_SMALL))
+@pytest.mark.parametrize("substrate,verify", _INTEGER_ROUTES,
+                         ids=[f"{s}-verify_{v}" for s, v in _INTEGER_ROUTES])
+def test_integer_substrates_take_the_planes_route(substrate, verify, spec,
+                                                  monkeypatch):
+    """On every integer substrate, ABFT verify included, a dense conv
+    writes int8 planes from the map and equals the f32 patch route bit
+    for bit."""
+    spec = _SMALL[spec]
+    cfg = pim.PimConfig(substrate=substrate, verify=verify)
+    ex, plan, b, x = _small_conv(spec, cfg, 7)
+    assert plan.planes.shape[1] > plan.k or spec.stride == 2
+    calls = []
+    real = cnn._im2col_planes
+    monkeypatch.setattr(cnn, "_im2col_planes",
+                        lambda *a: calls.append(1) or real(*a))
+    got = ex.conv(spec, x, relu=False)
+    assert calls == [1]
+    want = engine.matmul(cnn._im2col(x, spec), plan, bias=b)
+    assert got.shape == want.shape == (2, spec.out_h, spec.out_w,
+                                       spec.out_c)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, want)
+
+
+def test_emulate_keeps_the_f32_patches(monkeypatch):
+    """``emulate`` has no integer datapath: its dense conv builds the f32
+    patch matrix and multiplies it through ``engine.matmul``, never the
+    planes."""
+    spec = _SMALL["k27"]
+    cfg = pim.PimConfig(substrate=pim.EMULATE)
+    ex, plan, b, x = _small_conv(spec, cfg, 8)
+    monkeypatch.setattr(cnn, "_im2col_planes", None)   # must not be called
+    got = ex.conv(spec, x, relu=False)
+    want = engine.matmul(cnn._im2col(x, spec), plan, bias=b)
+    np.testing.assert_array_equal(got, want)
+    jaxpr = jax.make_jaxpr(lambda a: ex.conv(spec, a, relu=False))(x).jaxpr
+    k = spec.kh * spec.kw * spec.in_c
+    assert any(getattr(v, "shape", ()) == (2, spec.out_h, spec.out_w, k)
+               and v.dtype == jnp.float32 for v in _avals(jaxpr))
+
+
+def _f32_patch_conv(self, spec, x, w, bias):
+    """The f32 patch route, spelled out: every patch row in f32, then
+    ``engine.matmul`` on the layer's plan."""
+    return engine.matmul(cnn._im2col(x, spec), self._plan(spec.name, w),
+                         cfg=self.pim, bias=bias)
 
 
 def _forward(bits, f32_patches, monkeypatch):
@@ -115,8 +184,7 @@ def _forward(bits, f32_patches, monkeypatch):
     x = _map(ConvSpec("x", hw, hw, 3, 3, 1, 1), jax.random.PRNGKey(6))
     with monkeypatch.context() as mp:
         if f32_patches:
-            mp.setattr(cnn._Executor, "_quantizes_patches",
-                       lambda self: False)
+            mp.setattr(cnn._Executor, "_dense_conv", _f32_patch_conv)
         return np.asarray(jax.jit(lambda p, pl, a: cnn.cnn_forward(
             p, layers, a, pim=pcfg, plans=pl))(params, plans, x))
 
@@ -180,8 +248,7 @@ def test_forward_holds_no_float_patch_matrix(monkeypatch):
     assert _patch_shaped((fwd, params, plans, x), layers, b) == []
     # the check sees the f32 patches where they are
     with monkeypatch.context() as mp:
-        mp.setattr(cnn._Executor, "_quantizes_patches",
-                   lambda self: False)
+        mp.setattr(cnn._Executor, "_dense_conv", _f32_patch_conv)
         assert _patch_shaped((fwd, params, plans, x), layers, b)
 
 
@@ -197,7 +264,7 @@ def test_kernel_calls_unchanged_by_the_route(monkeypatch):
     fwd = lambda p, pl, a: cnn.cnn_forward(p, layers, a, pim=pcfg, plans=pl)
     got = pim.kernel_calls(fwd, params, plans, x)
     with monkeypatch.context() as mp:
-        mp.setattr(cnn._Executor, "_quantizes_patches", lambda self: False)
+        mp.setattr(cnn._Executor, "_dense_conv", _f32_patch_conv)
         want = pim.kernel_calls(fwd, params, plans, x)
     assert got == want
     assert [c.scope for c in got] == [f"{s.name}/{pim.SCOPE_KERNEL}"

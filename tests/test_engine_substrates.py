@@ -1,7 +1,7 @@
 """Engine substrate registry: parity of the exact substrates with the
 integer oracle (dense / depthwise / expert-stacked at w4a4 and w8a8),
 analog tolerance, emulate semantics, registry behavior (unknown-substrate
-errors, deprecated boolean-flag resolution), and plan persistence."""
+errors), the planes verb, and plan persistence."""
 import dataclasses
 
 import jax
@@ -11,7 +11,8 @@ import pytest
 
 from repro import engine
 from repro.core.pim import (DensePlan, DepthwisePlan, ExpertStackedPlan,
-                            PimConfig, prepare_weights,
+                            PimConfig, plan_from_qtensor, prepare_weights,
+                            quantize_activations,
                             reference_quantized_matmul)
 from repro.quant.quantize import fake_quantize, quantize
 
@@ -178,17 +179,6 @@ def test_register_substrate_round_trip():
         engine.substrates._REGISTRY.pop("test-custom", None)
 
 
-def test_deprecated_flags_resolve_with_warning():
-    with pytest.warns(DeprecationWarning, match="substrate='analog'"):
-        assert PimConfig(analog=True).resolved_substrate == "analog"
-    with pytest.warns(DeprecationWarning, match="substrate='exact-jnp'"):
-        assert PimConfig(use_pallas=False).resolved_substrate == "exact-jnp"
-    # defaults resolve silently; explicit substrate always wins
-    assert PimConfig().resolved_substrate == "exact-pallas"
-    assert PimConfig(substrate="analog",
-                     analog=False).resolved_substrate == "analog"
-
-
 def test_cfg_override_must_match_plan_bits():
     """A route-override cfg cannot silently reinterpret the programmed
     weight width (the plan's codes were decomposed at plan.bits)."""
@@ -205,22 +195,23 @@ def test_cfg_override_must_match_plan_bits():
 
 
 def test_legacy_qtensor_adoption_keeps_bit_width():
-    """pim_matmul with adopted non-default-width QTensor codes stamps the
-    plan cfg with the codes' width (regression: the override-bits guard
-    used to reject this documented legacy path)."""
-    from repro.core.pim import pim_matmul
+    """Adopting non-default-width QTensor codes with plan_from_qtensor
+    stamps the plan cfg with the codes' width, so engine.matmul on the
+    plan passes the override-bits guard and matches the oracle."""
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 48))
     w = jax.random.normal(jax.random.PRNGKey(1), (48, 16))
     w_q = quantize(w, bits=8, axis=(0,))
-    out = pim_matmul(x, w_q)
+    plan = plan_from_qtensor(w_q, PimConfig())
+    assert plan.bits == plan.cfg.weight_bits == 8
+    out = engine.matmul(x, plan)
     cfg8 = PimConfig(weight_bits=8)
     ref = reference_quantized_matmul(x, w_q, cfg8)
     assert jnp.array_equal(out, ref)
 
 
 def test_emulate_supports_wide_operands():
-    """The float-only emulate route keeps the old --pim-emulate behaviour
-    for bit widths above the int32 datapath's 8-bit limit."""
+    """The float-only emulate route serves bit widths above the int32
+    datapath's 8-bit limit."""
     cfg = _cfg("emulate", 16, 16)
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 32))
     w = jax.random.normal(jax.random.PRNGKey(1), (32, 8))
@@ -253,6 +244,73 @@ def test_substrate_stamped_into_plan_cfg():
     rerouted = engine.matmul(
         x, plan, cfg=dataclasses.replace(plan.cfg, substrate="exact-jnp"))
     assert jnp.array_equal(engine.matmul(x, plan), rerouted)
+
+
+@pytest.mark.parametrize("substrate",
+                         ("exact-pallas", "exact-jnp", "analog",
+                          "analog-pallas"))
+def test_matmul_planes_equals_matmul(substrate):
+    """engine.matmul_planes on the planes quantize_activations makes is
+    engine.matmul, bit for bit, on every integer substrate."""
+    cfg = _cfg(substrate)
+    x = jax.random.normal(jax.random.PRNGKey(0), (12, 40))
+    b = jax.random.normal(jax.random.PRNGKey(2), (24,))
+    plan = engine.program(jax.random.normal(jax.random.PRNGKey(1),
+                                            (40, 24)), cfg)
+    ap, a_scale = quantize_activations(x, plan, cfg)
+    assert ap.shape == (cfg.act_planes, 12, plan.planes.shape[1])
+    np.testing.assert_array_equal(
+        engine.matmul_planes(ap, a_scale, plan, bias=b),
+        engine.matmul(x, plan, bias=b))
+
+
+def test_matmul_planes_refuses_what_it_cannot_drive():
+    """matmul_planes serves unsharded DensePlans on an integer datapath
+    only: a plan split over a mesh, a float route, a depthwise plan and
+    planes at another K all raise ValueError."""
+    from jax.sharding import Mesh
+    x = jax.random.normal(jax.random.PRNGKey(0), (4, 40))
+    w = jax.random.normal(jax.random.PRNGKey(1), (40, 24))
+    plan = engine.program(w, _cfg("exact-jnp"))
+    ap, a_scale = quantize_activations(x, plan, plan.cfg)
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("model",))
+    sharded = engine.program(w, _cfg("exact-jnp"), mesh=mesh, spec="col")
+    assert sharded.shard is not None
+    with pytest.raises(ValueError, match="split over a mesh"):
+        engine.matmul_planes(ap, a_scale, sharded)
+    with pytest.raises(ValueError, match="no integer datapath"):
+        engine.matmul_planes(ap, a_scale, engine.program(w, _cfg("emulate")))
+    dw = engine.program(jnp.ones((9, 8)), _cfg("exact-jnp"),
+                        kind="depthwise")
+    with pytest.raises(ValueError, match="DepthwisePlan"):
+        engine.matmul_planes(ap, a_scale, dw)
+    with pytest.raises(ValueError, match="deep"):
+        engine.matmul_planes(jnp.pad(ap, ((0, 0), (0, 0), (0, 8))),
+                             a_scale, plan)
+
+
+def test_core_imports_nothing_from_engine():
+    """repro.core is the math layer under the engine: no module in it
+    imports repro.engine, so the package graph has no core -> engine ->
+    core cycle."""
+    import ast
+    import pathlib
+    import repro.core
+    root = pathlib.Path(repro.core.__file__).parent
+    found = []
+    for path in sorted(root.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [node.module or ""] + [
+                    f"{node.module}.{a.name}" for a in node.names]
+            else:
+                continue
+            found += [(path.name, n) for n in names
+                      if n == "repro.engine" or n.startswith("repro.engine.")]
+    assert len(list(root.rglob("*.py"))) > 5
+    assert found == []
 
 
 # ---------------------------------------------------------------------------
@@ -302,6 +360,45 @@ def test_load_plans_missing_and_unspecced(tmp_path):
     d = str(tmp_path / "plain")
     save_checkpoint(d, 0, {"w": jnp.ones((2, 2))})
     with pytest.raises(ValueError, match="engine_plans"):
+        engine.load_plans(d)
+
+
+def test_manifest_with_retired_cfg_keys_loads(tmp_path):
+    """Manifests written while PimConfig still had ``analog``,
+    ``use_pallas`` and ``cell_bits`` carry them in every plan's cfg. The
+    loader drops exactly those three; any other unknown key still
+    fails."""
+    import json
+    import os
+    cfg = _cfg("exact-jnp", 4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(0), (6, 32))
+    cols = jax.random.normal(jax.random.PRNGKey(1), (6, 9, 8))
+    tree = {"dense": engine.program(
+                jax.random.normal(jax.random.PRNGKey(2), (32, 16)), cfg),
+            "dw": engine.program(
+                jax.random.normal(jax.random.PRNGKey(3), (9, 8)), cfg,
+                kind="depthwise")}
+    d = str(tmp_path / "plans")
+    engine.save_plans(d, tree)
+    path = os.path.join(d, "step_00000000", "manifest.json")
+
+    def rewrite(extra):
+        with open(path) as f:
+            manifest = json.load(f)
+        for item in manifest["extras"]["engine_plans"]["items"].values():
+            item["cfg"].update(extra)
+        with open(path, "w") as f:
+            json.dump(manifest, f)
+
+    rewrite({"analog": False, "use_pallas": True, "cell_bits": 4})
+    restored, _, _ = engine.load_plans(d)
+    assert restored["dense"].cfg == tree["dense"].cfg
+    assert jnp.array_equal(engine.matmul(x, restored["dense"]),
+                           engine.matmul(x, tree["dense"]))
+    assert jnp.array_equal(engine.matmul(cols, restored["dw"]),
+                           engine.matmul(cols, tree["dw"]))
+    rewrite({"adc_gain": 2.0})
+    with pytest.raises(TypeError, match="adc_gain"):
         engine.load_plans(d)
 
 

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import pytest
 from hypo_compat import given, settings, st
 
-from repro.core.pim import (PimConfig, pim_matmul, prepare_weights,
+from repro import engine
+from repro.core.pim import (PimConfig, prepare_weights,
                             reference_quantized_matmul)
 
 
@@ -16,7 +17,7 @@ def test_exact_mode_bit_exact(wb, ab):
     w = jax.random.normal(jax.random.PRNGKey(1), (96, 24))
     cfg = PimConfig(weight_bits=wb, act_bits=ab)
     wq = prepare_weights(w, cfg)
-    assert jnp.array_equal(pim_matmul(x, wq, cfg),
+    assert jnp.array_equal(engine.matmul(x, wq, cfg=cfg),
                            reference_quantized_matmul(x, wq, cfg))
 
 
@@ -29,7 +30,7 @@ def test_exact_mode_bit_exact_shapes(m, k, n, seed):
     w = jax.random.normal(ks[1], (k, n))
     cfg = PimConfig(weight_bits=8, act_bits=8)
     wq = prepare_weights(w, cfg)
-    assert jnp.array_equal(pim_matmul(x, wq, cfg),
+    assert jnp.array_equal(engine.matmul(x, wq, cfg=cfg),
                            reference_quantized_matmul(x, wq, cfg))
 
 
@@ -39,7 +40,7 @@ def test_wraparound_large_k_exact():
     w = jax.random.normal(jax.random.PRNGKey(1), (8192, 8))
     cfg = PimConfig(weight_bits=8, act_bits=8)
     wq = prepare_weights(w, cfg)
-    assert jnp.array_equal(pim_matmul(x, wq, cfg),
+    assert jnp.array_equal(engine.matmul(x, wq, cfg=cfg),
                            reference_quantized_matmul(x, wq, cfg))
 
 
@@ -48,9 +49,10 @@ def test_analog_error_decreases_with_adc_bits():
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
     errs = []
     for adc in (4, 5, 8):
-        cfg = PimConfig(analog=True, adc_bits=adc, read_noise_sigma=1e-9)
+        cfg = PimConfig(substrate="analog", adc_bits=adc,
+                        read_noise_sigma=1e-9)
         wq = prepare_weights(w, cfg)
-        y = pim_matmul(x, wq, cfg, rng=jax.random.PRNGKey(2))
+        y = engine.matmul(x, wq, cfg=cfg, rng=jax.random.PRNGKey(2))
         ref = reference_quantized_matmul(x, wq, cfg)
         errs.append(float(jnp.linalg.norm(y - ref) / jnp.linalg.norm(ref)))
     assert errs[0] > errs[1] > errs[2]
@@ -61,9 +63,10 @@ def test_analog_noise_scales_with_sigma():
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
     outs = []
     for sigma in (1e-3, 5e-2):
-        cfg = PimConfig(analog=True, adc_bits=8, read_noise_sigma=sigma)
+        cfg = PimConfig(substrate="analog", adc_bits=8,
+                        read_noise_sigma=sigma)
         wq = prepare_weights(w, cfg)
-        y = pim_matmul(x, wq, cfg, rng=jax.random.PRNGKey(2))
+        y = engine.matmul(x, wq, cfg=cfg, rng=jax.random.PRNGKey(2))
         ref = reference_quantized_matmul(x, wq, cfg)
         outs.append(float(jnp.linalg.norm(y - ref)))
     assert outs[1] > outs[0]
@@ -72,12 +75,12 @@ def test_analog_noise_scales_with_sigma():
 def test_pallas_path_matches_jnp_path():
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
-    cfg_j = PimConfig(weight_bits=8, act_bits=4, use_pallas=False)
-    cfg_p = PimConfig(weight_bits=8, act_bits=4, use_pallas=True,
+    cfg_j = PimConfig(weight_bits=8, act_bits=4, substrate="exact-jnp")
+    cfg_p = PimConfig(weight_bits=8, act_bits=4, substrate="exact-pallas",
                       interpret=True)
     wq = prepare_weights(w, cfg_j)
-    assert jnp.array_equal(pim_matmul(x, wq, cfg_j),
-                           pim_matmul(x, wq, cfg_p))
+    assert jnp.array_equal(engine.matmul(x, wq, cfg=cfg_j),
+                           engine.matmul(x, wq, cfg=cfg_p))
 
 
 def test_rejects_wide_operands():
@@ -85,4 +88,5 @@ def test_rejects_wide_operands():
     w = jnp.ones((4, 2))
     cfg = PimConfig(weight_bits=16, act_bits=8)
     with pytest.raises(NotImplementedError):
-        pim_matmul(x, prepare_weights(w, PimConfig(weight_bits=8)), cfg)
+        engine.matmul(x, prepare_weights(w, PimConfig(weight_bits=8)),
+                      cfg=cfg)

@@ -1,4 +1,4 @@
-"""Weight-stationary engine: PlannedWeights reuse, decomposition-once
+"""Weight-stationary engine: DensePlan reuse, decomposition-once
 accounting, fused Pallas epilogue exactness, depthwise engine route, and
 the serving metrics it feeds."""
 import jax
@@ -7,8 +7,8 @@ import numpy as np
 import pytest
 
 import repro.core.pim as pim_mod
-from repro.core.pim import (PimConfig, PlannedWeights, pim_depthwise_matmul,
-                            pim_matmul, prepare_depthwise_weights,
+from repro import engine
+from repro.core.pim import (DensePlan, PimConfig, prepare_depthwise_weights,
                             prepare_weights, reference_quantized_matmul)
 from repro.kernels.pim_matmul.pim_matmul import pim_matmul_fused_pallas
 from repro.kernels.pim_matmul.ref import pim_matmul_fused_ref
@@ -22,17 +22,19 @@ def test_planned_weights_reused_bit_identical(wb, ab):
     cfg = PimConfig(weight_bits=wb, act_bits=ab)
     w = jax.random.normal(jax.random.PRNGKey(0), (96, 40))
     plan = prepare_weights(w, cfg)
-    assert isinstance(plan, PlannedWeights)
-    assert cfg.use_pallas, "exact mode must default to the Pallas kernel"
+    assert isinstance(plan, DensePlan)
+    assert cfg.substrate == "exact-pallas", \
+        "exact mode must default to the Pallas kernel"
     for seed in (1, 2):
         x = jax.random.normal(jax.random.PRNGKey(seed), (16, 96))
-        assert jnp.array_equal(pim_matmul(x, plan, cfg),
+        assert jnp.array_equal(engine.matmul(x, plan, cfg=cfg),
                                reference_quantized_matmul(x, plan, cfg))
 
 
 def test_plane_decomposition_once_per_weight_matrix(monkeypatch):
     """Nibble decomposition of the weight codes happens exactly once, at
-    prepare_weights time — pim_matmul only ever decomposes activations."""
+    prepare_weights time — engine.matmul only ever decomposes
+    activations."""
     calls = []
     real = pim_mod.to_nibbles
 
@@ -49,9 +51,9 @@ def test_plane_decomposition_once_per_weight_matrix(monkeypatch):
     calls.clear()
     x = jax.random.normal(jax.random.PRNGKey(1), (16, 96))
     for _ in range(3):
-        pim_matmul(x, plan, cfg)
+        engine.matmul(x, plan, cfg=cfg)
     assert calls == [(16, 96)] * 3, (
-        f"pim_matmul must only decompose activations, saw {calls}")
+        f"engine.matmul must only decompose activations, saw {calls}")
 
 
 def test_fused_epilogue_matches_jnp_path_exactly():
@@ -59,12 +61,13 @@ def test_fused_epilogue_matches_jnp_path_exactly():
     on both 4-bit (one-plane) and 8-bit (two-plane) operands."""
     for bits in (4, 8):
         cfg_p = PimConfig(weight_bits=bits, act_bits=bits)
-        cfg_j = PimConfig(weight_bits=bits, act_bits=bits, use_pallas=False)
+        cfg_j = PimConfig(weight_bits=bits, act_bits=bits,
+                          substrate="exact-jnp")
         w = jax.random.normal(jax.random.PRNGKey(0), (200, 72))
         x = jax.random.normal(jax.random.PRNGKey(1), (33, 200))
         plan = prepare_weights(w, cfg_p)
-        assert jnp.array_equal(pim_matmul(x, plan, cfg_p),
-                               pim_matmul(x, plan, cfg_j))
+        assert jnp.array_equal(engine.matmul(x, plan, cfg=cfg_p),
+                               engine.matmul(x, plan, cfg=cfg_j))
 
 
 def test_fused_kernel_matches_fused_ref():
@@ -91,8 +94,8 @@ def test_fused_bias_within_one_ulp():
     b = jax.random.normal(jax.random.PRNGKey(2), (24,))
     x = jax.random.normal(jax.random.PRNGKey(1), (16, 96))
     plan = prepare_weights(w, cfg)
-    fused = pim_matmul(x, plan, cfg, bias=b)
-    two_step = pim_matmul(x, plan, cfg) + b[None, :]
+    fused = engine.matmul(x, plan, cfg=cfg, bias=b)
+    two_step = engine.matmul(x, plan, cfg=cfg) + b[None, :]
     np.testing.assert_allclose(np.asarray(fused), np.asarray(two_step),
                                rtol=1.5e-7, atol=1e-7)
 
@@ -108,7 +111,7 @@ def test_planned_weights_flow_through_jit_and_scan():
     @jax.jit
     def run(x, stacked):
         def body(c, plan):
-            return c, pim_matmul(x, plan, cfg)
+            return c, engine.matmul(x, plan, cfg=cfg)
         return jax.lax.scan(body, 0, stacked)[1]
 
     ys = run(x, stacked)
@@ -124,7 +127,7 @@ def test_depthwise_engine_route_exact():
     cols = jax.random.normal(jax.random.PRNGKey(0), (50, 9, 12))
     w = jax.random.normal(jax.random.PRNGKey(1), (9, 12))
     plan = prepare_depthwise_weights(w, cfg)
-    out = pim_depthwise_matmul(cols, plan, cfg)
+    out = engine.matmul(cols, plan, cfg=cfg)
     # oracle: quantized int32 per-channel dot, dequantized
     w_q = quantize(w, bits=cfg.weight_bits, axis=(0,))
     a_q = quantize(cols, bits=cfg.act_bits, axis=(1,))
@@ -150,17 +153,17 @@ def test_cnn_depthwise_pim_regression():
     from repro.models.cnn import _im2col
     spec0, spec1 = layers
     cols0 = _im2col(x, spec0)
-    h = jax.nn.relu(pim_matmul(
+    h = jax.nn.relu(engine.matmul(
         cols0, prepare_weights(params[spec0.name]["w"].reshape(-1,
                                                                spec0.out_c),
-                               cfg), cfg, bias=params[spec0.name]["b"]))
+                               cfg), cfg=cfg, bias=params[spec0.name]["b"]))
     cols1 = _im2col(h, spec1)
     b, oh, ow, _ = cols1.shape
     cols1 = cols1.reshape(b, oh, ow, spec1.kh * spec1.kw, spec1.in_c)
     wd = params[spec1.name]["w"].reshape(spec1.kh * spec1.kw, spec1.in_c)
     # dw0 is the stack's last spec, so cnn_forward skips its ReLU
-    ref = pim_depthwise_matmul(
-        cols1, prepare_depthwise_weights(wd, cfg), cfg) \
+    ref = engine.matmul(
+        cols1, prepare_depthwise_weights(wd, cfg), cfg=cfg) \
         + params[spec1.name]["b"]
     out_ref = jnp.mean(ref, axis=(1, 2))
     assert jnp.array_equal(got, out_ref)
@@ -206,12 +209,12 @@ def test_serve_throughput_metric_accounts_for_batch():
 @pytest.mark.slow
 def test_serve_real_pim_path_smoke():
     """End-to-end: planned-weight PIM execution through prefill + decode
-    (projection matmuls on the engine), plus the emulate escape hatch."""
+    (projection matmuls on the engine), plus the emulate substrate."""
     from repro.launch.serve import serve
     res = serve("qwen3-4b", batch=1, prompt_len=8, gen=3, layers=1,
                 d_model=32, pim=True)
     assert res["generated"].shape == (1, 3)
     assert res["opima_tokens_per_s"] > 0
     res_em = serve("qwen3-4b", batch=1, prompt_len=8, gen=3, layers=1,
-                   d_model=32, pim=True, pim_emulate=True)
+                   d_model=32, pim=True, pim_substrate="emulate")
     assert res_em["generated"].shape == (1, 3)

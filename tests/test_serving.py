@@ -298,8 +298,7 @@ def test_setup_layers_cut(monkeypatch, d_model):
     monkeypatch.setattr(serve_mod, "init_lm", capture)
     with pytest.raises(Built) as exc:
         serve_mod._setup("qwen2.5-3b", 3, d_model, pim=False, pim_bits=4,
-                         pim_emulate=False, pim_substrate=None,
-                         plan_dir=None)
+                         pim_substrate=None, plan_dir=None)
     cfg, full = exc.value.args[0], get_config("qwen2.5-3b")
     assert cfg.num_layers == 3
     if d_model is None:
